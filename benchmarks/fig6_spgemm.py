@@ -16,7 +16,8 @@ import functools, time
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.spgemm import spgemm_summa
 
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+from repro.compat import make_mesh
+mesh = make_mesh((2, 2), ('data', 'model'))
 rng = np.random.default_rng(0)
 M, K, N = 512, 512, 256
 def sprand(m, n, frac=0.05):
@@ -39,6 +40,10 @@ for alg in ['incremental', 'tree', 'sorted', 'spa']:
 
 
 def main():
+    from repro.compat import backend_initialized
+    if backend_initialized():
+        raise RuntimeError("start this bench before anything in the process "
+                           "initialises jax: its child needs the devices")
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")

@@ -15,8 +15,26 @@ Paper artifact -> module:
 from __future__ import annotations
 
 import argparse
+import importlib
 import sys
 import traceback
+
+
+#: (job name, module, entry) in run order. The two multi-device benches
+#: run in child processes and come first: once this process initialises a
+#: jax backend it may hold the chip, and a child that needs it would fail or
+#: hang. Modules are imported per job, so nothing touches jax before then.
+JOBS = [
+    ("fig6_spgemm", "fig6_spgemm", "main"),
+    ("sparse_allreduce", "sparse_allreduce_bytes", "main"),
+    ("table3_er", "table34_algorithms", "run:er"),
+    ("table4_rmat", "table34_algorithms", "run:rmat"),
+    ("fig2_regions", "fig2_regions", "main"),
+    ("fig3_scaling", "fig3_scaling", "main"),
+    ("fig4_blocksize", "fig4_blocksize", "main"),
+    ("kv_quant_roofline", "kv_quant_roofline", "main"),
+]
+MULTIDEVICE = {"fig6_spgemm", "sparse_allreduce"}
 
 
 def main() -> None:
@@ -26,31 +44,18 @@ def main() -> None:
                     help="skip benches that spawn multi-device subprocesses")
     args = ap.parse_args()
 
-    from benchmarks import (fig2_regions, fig3_scaling, fig4_blocksize,
-                            fig6_spgemm, kv_quant_roofline,
-                            sparse_allreduce_bytes, table34_algorithms)
-
-    jobs = {
-        "table3_er": lambda: table34_algorithms.run("er"),
-        "table4_rmat": lambda: table34_algorithms.run("rmat"),
-        "fig2_regions": fig2_regions.main,
-        "fig3_scaling": fig3_scaling.main,
-        "fig4_blocksize": fig4_blocksize.main,
-        "fig6_spgemm": fig6_spgemm.main,
-        "sparse_allreduce": sparse_allreduce_bytes.main,
-        "kv_quant_roofline": kv_quant_roofline.main,
-    }
-    multidev = {"fig6_spgemm", "sparse_allreduce"}
-
     failures = []
-    for name, fn in jobs.items():
+    for name, module, entry in JOBS:
         if args.only and args.only != name:
             continue
-        if args.skip_multidevice and name in multidev:
+        if args.skip_multidevice and name in MULTIDEVICE:
             continue
         print(f"# --- {name} ---", flush=True)
         try:
-            fn()
+            mod = importlib.import_module(f"benchmarks.{module}")
+            fn_name, _, arg = entry.partition(":")
+            fn = getattr(mod, fn_name)
+            fn(arg) if arg else fn()
         except Exception:
             traceback.print_exc()
             failures.append(name)

@@ -35,6 +35,7 @@ from repro.sharding.params import ef_shardings
 from repro.optim import adamw_init
 from repro.data import make_batch
 from repro.launch.hlo_analysis import ModuleAnalyzer
+from repro.compat import make_mesh
 
 knobs = json.loads(sys.argv[1])
 D, T = knobs['mesh']
@@ -51,10 +52,10 @@ hp = TrainHParams(ce_chunk=64, attn_chunk=64, remat=False,
 shape = ShapeConfig('b', 'train', knobs['seq'], knobs['batch'])
 batch = make_batch(cfg, shape, 0)
 if T > 1:
-    mesh = jax.make_mesh((D, T), ('data', 'model'))
+    mesh = make_mesh((D, T), ('data', 'model'))
     baxes, tag = ('data', 'model'), f'allreduce_{D}x{T}'
 else:
-    mesh = jax.make_mesh((D,), ('data',))
+    mesh = make_mesh((D,), ('data',))
     baxes, tag = 'data', 'allreduce'
 
 bsh = jax.tree.map(
@@ -97,6 +98,10 @@ SMOKE_KNOBS = dict(layers=2, d_model=128, d_ff=256, vocab=512,
 
 def run_mesh(mesh: tuple[int, int], knobs: dict) -> list[dict]:
     """Fork a child with D*T fake devices and collect its emitted records."""
+    from repro.compat import backend_initialized
+    if backend_initialized():
+        raise RuntimeError("start this bench before anything in the process "
+                           "initialises jax: its child needs the devices")
     d, t = mesh
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d * t}"

@@ -56,10 +56,9 @@ def run(kind: str, m=2048, n=32, ks=(4, 16, 64), ds=(4, 16, 64),
                 rows[(alg, k, d)] = us
                 emit(f"table_{kind}/{alg}/k={k}/d={d}", us,
                      f"nnz_in={k * d * n}")
-            # the serial-store story at this cell: O(chunk) -> O(distinct)
+            # the serial-store story at this cell: O(chunk) vs one-hot's 0
             sc = _store_counts(mats)
-            emit(f"table_{kind}/stores/k={k}/d={d}", sc["sort_fold"],
-                 f"serial={sc['serial']} sort_fold={sc['sort_fold']} "
+            emit(f"table_{kind}/stores/k={k}/d={d}", sc["serial"],
                  f"onehot_fold={sc['onehot_fold']}")
             # the engine's pick for this cell, timed under the same harness
             us = time_fn(jax.jit(spkadd_auto), mats)
@@ -130,8 +129,6 @@ def smoke(kind="er", k=6, m=64, n=8, d=4) -> int:
         failures += (not ok)
     sc = _store_counts(mats)
     emit("smoke/serial_stores", float(sc["serial"]), "serial fold")
-    emit("smoke/sort_fold_stores", float(sc["sort_fold"]),
-         "vec sort-fold (O(distinct runs))")
     if failures:
         emit("smoke/FAILED", float(failures), "cross-regime mismatches")
     else:

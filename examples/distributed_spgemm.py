@@ -1,13 +1,12 @@
 """Distributed SpGEMM (sparse SUMMA) with SpKAdd reduction — paper Fig. 5/6.
 
-Spawns itself with 4 fake devices if needed, multiplies two sparse matrices
-on a 2×2 process grid, and compares reduction algorithms.
+Multiplies two sparse matrices on a 2×2 process grid and compares reduction
+algorithms. The grid is the first four accelerators when the host has four,
+else four virtual CPU devices — in this one process either way.
 
 Run: PYTHONPATH=src python examples/distributed_spgemm.py
 """
 import os
-import subprocess
-import sys
 
 
 def run():
@@ -20,7 +19,11 @@ def run():
 
     from repro.core.spgemm import spgemm_summa
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.compat import make_mesh
+    devices = jax.devices()
+    if len(devices) < 4:
+        devices = jax.devices("cpu")
+    mesh = make_mesh((2, 2), ("data", "model"), devices=devices[:4])
     rng = np.random.default_rng(0)
     M, K, N = 512, 512, 256
 
@@ -50,11 +53,10 @@ def run():
 
 
 if __name__ == "__main__":
-    if len(jax.devices()) < 4 if "jax" in sys.modules else True:
-        if os.environ.get("_SPGEMM_CHILD") != "1":
-            env = dict(os.environ)
-            env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-            env["_SPGEMM_CHILD"] = "1"
-            sys.exit(subprocess.run([sys.executable, __file__], env=env).returncode)
-    import jax  # noqa: E402
+    # the CPU backend reads its device count when jax first initialises it,
+    # so the four virtual devices are requested before jax is imported
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            flags + " --xla_force_host_platform_device_count=4").strip()
     run()

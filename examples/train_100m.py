@@ -60,7 +60,8 @@ def main():
         n_dev = len(jax.devices())
         assert n_dev > 1, ("--compress needs a DP mesh: set XLA_FLAGS="
                            "--xla_force_host_platform_device_count=4")
-        mesh = jax.make_mesh((n_dev,), ("data",))
+        from repro.compat import make_mesh
+        mesh = make_mesh((n_dev,), ("data",))
         step_impl = jax.jit(make_compressed_train_step(
             model, mesh, hp, k_fraction=args.k_fraction,
             schedule=args.schedule))

@@ -43,6 +43,7 @@ WEIGHTS = {
     "test_extensions.py": 3,
     "test_sharding.py": 2,
     "test_obs.py": 2,
+    "test_chip_smoke.py": 3,
 }
 
 TESTS_DIR = os.path.join(

@@ -33,21 +33,21 @@ from repro.analysis.findings import Finding
 
 
 def _subjaxprs(params: dict):
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for v in params.values():
         vs = v if isinstance(v, (list, tuple)) else [v]
         for item in vs:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, Jaxpr):
                 yield item
 
 
 def iter_eqns(jaxpr):
     """Every eqn in a (Closed)Jaxpr, recursing into sub-jaxpr params
     (pjit, scan, while, cond branches, custom_* call jaxprs, ...)."""
-    import jax
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    from jax.extend.core import ClosedJaxpr
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
@@ -200,7 +200,7 @@ def geometry_matrix() -> Iterable[Tuple[str, Callable[[], object], int]]:
     # sparse allreduce, gather_kway with the vec accumulator: the local
     # k-way fold's single pre-sort
     if jax.device_count() >= 1:
-        mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("dp",))
+        mesh = compat.make_mesh((1,), ("dp",), devices=jax.devices()[:1])
         u = SparseUpdate(idx=jnp.arange(8, dtype=jnp.int32),
                          val=jnp.ones((8,), jnp.float32), size=64)
 

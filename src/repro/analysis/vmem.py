@@ -5,22 +5,25 @@ The estimator re-uses the runtime's own working-set formula
 runtime's own chooser would pick (:func:`partitioned_launch_geometry` +
 ``engine._partition_fold``), then compares against a per-backend hard cap
 — so the static proof and the runtime budget cannot drift apart. The cap
-is the physical per-core VMEM (16 MiB on every currently-targeted TPU
-generation), not the requested soft budget: the lane-multiple floors in
-the geometry chooser are sanctioned excess over a sub-minimal *budget*,
-but nothing may exceed the *cap*.
+is the ``vmem_limit_bytes`` every engine kernel passes to Mosaic
+(``repro.kernels.VMEM_LIMIT_BYTES``), not the requested soft budget: the
+lane-multiple floors in the geometry chooser are sanctioned excess over a
+sub-minimal *budget*, but nothing may exceed the *cap*. On a v5e core the
+cap sits inside the 128 MiB of physical VMEM; 16 MiB is only Mosaic's
+default scoped limit, which applies to kernels that pass no limit.
 """
 from __future__ import annotations
 
 from typing import Dict, List, Optional
 
 from repro.analysis.findings import Finding
+from repro.kernels import VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES
 
-#: Hard per-core fast-memory caps (bytes). "interpret" models the TPU cap
-#: so interpret-mode CI proves the geometry that will ship to hardware.
+#: Per-kernel fast-memory caps (bytes). "interpret" models the TPU cap so
+#: interpret-mode CI proves the geometry that will ship to hardware.
 BACKEND_VMEM_CAPS: Dict[str, int] = {
-    "tpu": 16 * 1024 * 1024,
-    "interpret": 16 * 1024 * 1024,
+    "tpu": VMEM_LIMIT_BYTES,
+    "interpret": VMEM_LIMIT_BYTES,
 }
 
 DEFAULT_BACKEND = "interpret"
@@ -34,7 +37,7 @@ def working_set_bytes(fold: str, *, part_elems: int, chunk: int) -> int:
 
 
 def check_launch(*, cap: int, m: int, n: int,
-                 vmem_budget_bytes: int = 16 * 1024 * 1024,
+                 vmem_budget_bytes: int = VMEM_BUDGET_BYTES,
                  part_elems: Optional[int] = None,
                  chunk: Optional[int] = None,
                  regime: str = "vec",

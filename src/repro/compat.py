@@ -1,89 +1,69 @@
-"""Version-skew shims for the jax API surface this repo depends on.
+"""The one home for the jax API surface that moves between releases.
 
-Two things drifted across the jax versions we target:
-
-- ``shard_map`` lives at ``jax.experimental.shard_map.shard_map`` up to
-  jax 0.4.x and graduates to ``jax.shard_map`` later; the replication-check
-  kwarg is renamed ``check_rep`` -> ``check_vma`` in the same move.
-- ``Compiled.cost_analysis()`` returns a single dict on newer jax but a
-  *list* of per-computation dicts on 0.4.x, so ``ca["flops"]`` raises
-  ``TypeError`` there.
-
-Import from here instead of feature-testing jax at every call site.
-
-This module is also the **one sanctioned home for ``jax.experimental``
-imports** (spkaddlint rule SPK102): experimental APIs move between jax
-releases, so every consumer routes through the re-exports below
-(``pallas`` / ``pallas_tpu`` / ``shard_map``) and version skew stays a
-one-file problem.
+The repo targets jax 0.9.0 only (``requirements.txt``). This module is the
+**one sanctioned home for ``jax.experimental`` imports** (spkaddlint rule
+SPK102): experimental APIs move between jax releases, so every consumer
+routes through the re-exports below (``pallas`` / ``pallas_tpu`` /
+``topologies``) and the next upgrade stays a one-file problem. It also
+holds the two process-wide decisions that depend on the installed jax:
+how a mesh is built (:func:`make_mesh`) and whether Pallas kernels run
+through Mosaic or the interpreter (:func:`interpret_kernels`).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax.experimental import pallas as pallas
+from jax.experimental import topologies as topologies
+from jax.experimental.pallas import tpu as pallas_tpu
+from jax.sharding import AxisType
 
-try:  # jax >= 0.6: public top-level export
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# Pallas: experimental on every jax we target. Kernels import these
-# re-exports; a build without Pallas (minimal CPU wheels) leaves them None
-# and the kernel modules fail at import with a clear message instead of a
-# deep attribute error.
-try:
-    from jax.experimental import pallas as pallas
-except ImportError:  # pragma: no cover - jax always ships pallas today
-    pallas = None  # type: ignore[assignment]
-try:
-    from jax.experimental.pallas import tpu as pallas_tpu
-except ImportError:  # pragma: no cover - CPU-only builds lack the TPU dialect
-    pallas_tpu = None  # type: ignore[assignment]
+shard_map = jax.shard_map
 
 
-def require_pallas():
-    """Return the ``pallas`` module or raise a actionable ImportError."""
-    if pallas is None:
-        raise ImportError(
-            "jax.experimental.pallas is unavailable in this jax build; "
-            "the repro.kernels package requires it")
-    return pallas
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
 
-_REP_KWARG = ("check_rep" if "check_rep"
-              in inspect.signature(_shard_map).parameters else "check_vma")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """``jax.shard_map`` on any supported jax.
-
-    ``check_vma`` follows the new-jax spelling; it is forwarded as
-    ``check_rep`` on jax versions that predate the rename.
+    jax 0.9 defaults to Explicit axes, under which ``with_sharding_constraint``
+    outside ``jax.set_mesh`` and specs that name one axis twice are refused.
+    The repo's sharding rules are written for Auto axes, so every mesh is
+    built here.
     """
-    if check_vma is not None:
-        kw[_REP_KWARG] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
-def axis_size(axis_name) -> int:
-    """Static size of a bound mesh axis (inside ``shard_map``) on any
-    supported jax: ``jax.lax.axis_size`` where it exists, else the axis-env
-    lookup that 0.4.x spells ``jax.core.axis_frame``."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.core.axis_frame(axis_name)
+def interpret_kernels() -> bool:
+    """Whether the engine's Pallas kernels run under the interpreter.
+
+    Decided once, from the backend: on a TPU every engine ``pallas_call``
+    lowers through Mosaic; on any other backend (the CPU test lanes) it
+    runs under the Pallas interpreter.
+    """
+    return jax.default_backend() != "tpu"
+
+
+def require_interpreter(kernel: str) -> None:
+    """Refuse to launch an interpreter-only reference kernel on a TPU.
+
+    The faithful hash, legacy sliding-SPA and block top-k kernels are CPU
+    references that the engine never reaches; they are not written for
+    Mosaic, so on a TPU they raise instead of quietly interpreting.
+    """
+    if not interpret_kernels():
+        raise NotImplementedError(
+            f"{kernel} is an interpreter-only CPU reference kernel; the "
+            f"engine regimes (spkadd_run / spkadd_auto) are the TPU paths")
 
 
 def backend_initialized() -> bool:
     """True iff jax has already initialized an XLA backend in this process —
     the point at which ``XLA_FLAGS`` is read and the device count locks.
 
-    Reads the private backend cache (``jax._src.xla_bridge._backends``, the
-    same home on every jax we target); if the internal layout ever drifts,
-    this *fails open* (returns False) — callers that need certainty about
-    the device count must check ``jax.device_count()`` after init, which
-    stays correct on any jax.
+    Reads the private backend cache (``jax._src.xla_bridge._backends``); if
+    the internal layout ever drifts, this *fails open* (returns False) —
+    callers that need certainty about the device count must check
+    ``jax.device_count()`` after init.
     """
     try:
         from jax._src import xla_bridge
@@ -93,13 +73,6 @@ def backend_initialized() -> bool:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on any supported jax.
-
-    jax 0.4.x returns ``[dict]`` (one entry per computation; the entry-point
-    computation first) — take element 0. Newer jax returns the dict directly.
-    Returns ``{}`` when the backend reports nothing.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    """``compiled.cost_analysis()`` as a dict (``{}`` when the backend
+    reports nothing)."""
+    return dict(compiled.cost_analysis() or {})

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.compat import axis_size as _axis_size
 from repro.core.engine import scatter_accumulate
 from repro.core.topk import SparseUpdate
 
@@ -77,7 +76,7 @@ def halving_2way(u: SparseUpdate, axis: str) -> jax.Array:
     *not* re-top-k'd between rounds (lossless), so widths double each round:
     the bytes tell the tree-vs-kway story the paper's Table I tells for I/O.
     """
-    p = _axis_size(axis)
+    p = jax.lax.axis_size(axis)
     if p & (p - 1) != 0:
         raise ValueError("halving_2way needs a power-of-two axis")
     me = jax.lax.axis_index(axis)
@@ -102,7 +101,7 @@ def ring_2way(u: SparseUpdate, axis: str) -> jax.Array:
     the O(k²)-ish data movement of Alg. 1 shows up as the widening ppermute
     payloads.
     """
-    p = _axis_size(axis)
+    p = jax.lax.axis_size(axis)
     perm = [(i, (i + 1) % p) for i in range(p)]
     idx, val = u.idx, u.val
     acc_idx, acc_val = idx, val
@@ -156,7 +155,7 @@ def sparse_allreduce(u: SparseUpdate, axis: str,
     except KeyError:
         raise ValueError(f"unknown schedule {schedule!r}; "
                          f"choose from {sorted(SCHEDULES)}") from None
-    p = _axis_size(axis)
+    p = jax.lax.axis_size(axis)
     s = int(u.idx.shape[0])
     nbytes = modeled_schedule_bytes(schedule, p, s)
     obs.counter(f"allreduce.calls.{schedule}").inc()
@@ -250,7 +249,7 @@ def compressed_gradient_mean_2d(grads, residuals, data_axis: str,
     if model_reduce not in ("reduce_scatter", "psum"):
         raise ValueError(f"unknown model_reduce {model_reduce!r}; "
                          "choose 'reduce_scatter' or 'psum'")
-    t = _axis_size(model_axis)
+    t = jax.lax.axis_size(model_axis)
 
     def one_leaf(g, r):
         flat = g.reshape(-1)

@@ -77,6 +77,7 @@ from repro.core.sparse import (CompressPlan, PaddedCOO, compress_plan, concat,
                                next_pow2, plan_and_partition, sentinel_key,
                                sort_calls, stable_argsort, with_capacity)
 from repro.core import spkadd as _alg
+from repro.kernels import VMEM_BUDGET_BYTES
 
 _log = logging.getLogger("repro.engine")
 
@@ -156,8 +157,8 @@ DEFAULT_COST_MODEL: Dict[str, float] = {
     # vec regime: the lane-parallel sliding accumulator (kernels/vec_accum) —
     # the production pick for accumulators past the dense-SPA budget. Tiles
     # at or below vec_onehot_max_block_elems use the one-hot MXU fold
-    # (O(chunk·block_elems) FLOPs, zero serial stores); larger tiles use the
-    # bitonic sort-fold (O(distinct-runs) serial stores).
+    # (zero serial stores); larger tiles use the serial fold (one tile-row
+    # store per element).
     "vec_max_accum_elems": float(1 << 26),
     "vec_min_density": 1.0 / 32.0,
     "vec_onehot_max_block_elems": 4096.0,
@@ -289,8 +290,10 @@ def load_cost_model(path: str) -> Dict[str, float]:
 def scatter_accumulate(keys: jax.Array, vals: jax.Array,
                        length: int) -> jax.Array:
     """Dense SPA numeric phase: fold a (key, val) stream into a flat
-    accumulator of ``length`` slots, in stream order. Keys outside
-    ``[0, length)`` (sentinels) land in a discard slot.
+    accumulator of ``length`` slots. Keys outside ``[0, length)``
+    (sentinels) land in a discard slot. Duplicate keys add in stream order
+    only where the backend's scatter does so: on a TPU, pass key-sorted
+    streams (``_run_spa``).
 
     This is the one scatter every dense consumer shares — the engine's SPA
     regime, the sparse-allreduce k-way schedule, and ``to_dense`` semantics.
@@ -300,14 +303,18 @@ def scatter_accumulate(keys: jax.Array, vals: jax.Array,
     return acc[:length]
 
 
-def _canonical_gather(out_keys: jax.Array, nnz: jax.Array, flat: jax.Array,
+def _canonical_gather(out_keys: jax.Array, nnz: jax.Array, acc: jax.Array,
                       sent: int, dtype) -> jax.Array:
     """The canonical value gather every dense-accumulator regime shares —
     single-collection and batched (vmapped) paths must use this one
-    function so their sentinel/nnz/dtype conventions can never diverge."""
+    function so their sentinel/nnz/dtype conventions can never diverge.
+    ``acc`` is a flat key-ordered accumulator or the partitioned launch's
+    ``(rows, width)`` form (``kernels.ops.take_keys``)."""
+    from repro.kernels.ops import take_keys
+
     gather_keys = jnp.where(out_keys != sent, out_keys, 0)
     return jnp.where(jnp.arange(out_keys.shape[0]) < nnz,
-                     flat[gather_keys], 0.0).astype(dtype)
+                     take_keys(acc, gather_keys), 0.0).astype(dtype)
 
 
 def _canonical_from_plan(cat: PaddedCOO, plan: CompressPlan,
@@ -320,26 +327,26 @@ def _canonical_from_plan(cat: PaddedCOO, plan: CompressPlan,
                      shape=cat.shape)
 
 
-def _canonical_from_flat(cat: PaddedCOO, flat: jax.Array) -> PaddedCOO:
-    """Pair the canonical structural layout of ``cat`` with per-key values
-    gathered from a dense accumulator ``flat`` (col-major, ``flat[key]``)."""
-    return _canonical_from_plan(cat, compress_plan(cat.keys, cat.shape), flat)
-
-
 def _run_spa(mats: Sequence[PaddedCOO],
              cost_model: Optional[Dict[str, float]] = None) -> PaddedCOO:
     """SPA regime: one-touch dense scatter for the numeric phase, canonical
-    structural layout for the output."""
+    structural layout for the output. The scatter takes the stream in the
+    plan's stable key order: XLA leaves the order in which a scatter
+    combines duplicate indices unspecified, and on a TPU an unsorted
+    scatter does not add them in stream order, while a key-sorted one folds
+    them as ``segment_sum`` does in the ``sorted`` regime."""
     cat = concat(mats)
     m, n = cat.shape
-    flat = scatter_accumulate(cat.keys, cat.vals, m * n)
-    return _canonical_from_flat(cat, flat)
+    plan = compress_plan(cat.keys, cat.shape)
+    flat = scatter_accumulate(cat.keys[plan.order], cat.vals[plan.order],
+                              m * n)
+    return _canonical_from_plan(cat, plan, flat)
 
 
 def _partition_fold(regime: str, geom, vmem_budget_bytes: int,
                     cost_model: Optional[Dict[str, float]]) -> str:
     """In-tile fold for a partitioned launch: ``blocked_spa`` keeps the
-    serial fidelity scatter; ``vec`` picks one-hot vs sort-fold on the cost
+    serial fidelity scatter; ``vec`` picks one-hot vs serial on the cost
     model's tile-size boundary (one-hot additionally requires its whole
     step working set — tile, double-buffered inputs, and the
     ``(chunk × part_elems)`` intermediates — to fit the VMEM budget; see
@@ -354,12 +361,12 @@ def _partition_fold(regime: str, geom, vmem_budget_bytes: int,
     onehot_ws = kops.fold_working_set_bytes(
         "onehot", tile_elems=geom.part_elems, chunk=geom.chunk)
     return "onehot" if (geom.part_elems <= cm["vec_onehot_max_block_elems"]
-                        and onehot_ws <= vmem_budget_bytes) else "sort"
+                        and onehot_ws <= vmem_budget_bytes) else "serial"
 
 
 def _partitioned_core(keys: jax.Array, vals: jax.Array,
                       shape: Tuple[int, int], regime: str,
-                      vmem_budget_bytes: int, interpret: bool,
+                      vmem_budget_bytes: int,
                       cost_model: Optional[Dict[str, float]]) -> PaddedCOO:
     """The ONE partitioned pipeline — plan/sort, step tables, Pallas launch,
     canonical gather — over ``(B, cap)`` concatenated streams. Both the
@@ -385,23 +392,22 @@ def _partitioned_core(keys: jax.Array, vals: jax.Array,
         vals_srt = jnp.take_along_axis(vals, plan.order, axis=-1)
         vals_p = jnp.zeros(keys_p.shape, jnp.float32).at[:, :cap].set(
             vals_srt.astype(jnp.float32))
-        flat = kops.partitioned_accumulate_flat(
+        acc = kops.partitioned_accumulate(
             keys_p, vals_p, steps.chunk_id, steps.part_id, m=m, n=n,
             part_elems=geom.part_elems, parts=geom.parts, chunk=geom.chunk,
-            fold=fold, interpret=interpret)
+            fold=fold)
 
     sent = sentinel_key(shape)
     out_vals = jax.vmap(
-        lambda ok, p_nnz, b_flat: _canonical_gather(ok, p_nnz, b_flat, sent,
-                                                    vals.dtype)
-    )(plan.out_keys, plan.nnz, flat)
+        lambda ok, p_nnz, b_acc: _canonical_gather(ok, p_nnz, b_acc, sent,
+                                                   vals.dtype)
+    )(plan.out_keys, plan.nnz, acc)
     return PaddedCOO(keys=plan.out_keys, vals=out_vals, nnz=plan.nnz,
                      shape=shape)
 
 
 def _run_partitioned(mats: Sequence[PaddedCOO], regime: str,
-                     vmem_budget_bytes: int = 16 * 1024 * 1024,
-                     interpret: bool = True,
+                     vmem_budget_bytes: int = VMEM_BUDGET_BYTES,
                      cost_model: Optional[Dict[str, float]] = None
                      ) -> PaddedCOO:
     """One-pass partitioned regimes (``vec`` / ``blocked_spa``): one stable
@@ -411,7 +417,7 @@ def _run_partitioned(mats: Sequence[PaddedCOO], regime: str,
     Runs the shared core as a B = 1 batch."""
     cat = concat(mats)
     out = _partitioned_core(cat.keys[None], cat.vals[None], cat.shape,
-                            regime, vmem_budget_bytes, interpret, cost_model)
+                            regime, vmem_budget_bytes, cost_model)
     return PaddedCOO(keys=out.keys[0], vals=out.vals[0], nnz=out.nnz[0],
                      shape=cat.shape)
 
@@ -435,7 +441,7 @@ def _run_vec(mats: Sequence[PaddedCOO],
 
 
 def _hash_core(keys: jax.Array, vals: jax.Array, shape: Tuple[int, int],
-               vmem_budget_bytes: int, interpret: bool,
+               vmem_budget_bytes: int,
                cost_model: Optional[Dict[str, float]]) -> PaddedCOO:
     """The ONE sort-free sliding-hash pipeline over ``(B, cap)`` streams.
 
@@ -467,8 +473,7 @@ def _hash_core(keys: jax.Array, vals: jax.Array, shape: Tuple[int, int],
                   num_chunks=geom.num_chunks):
         tkeys, tvals = kops.hash_slide_tables(
             keys, vals, m=m, n=n, table_size=geom.table_size,
-            part_span=geom.part_span, parts=geom.parts, chunk=geom.chunk,
-            interpret=interpret)
+            part_span=geom.part_span, parts=geom.parts, chunk=geom.chunk)
     # the zero-presort pin: tables were built without any canonical sort
     obs.gauge("engine.hash.presort_sorts").set(sort_calls() - sorts_before)
 
@@ -498,14 +503,13 @@ def _hash_core(keys: jax.Array, vals: jax.Array, shape: Tuple[int, int],
 
 def _run_hash(mats: Sequence[PaddedCOO],
               cost_model: Optional[Dict[str, float]] = None,
-              vmem_budget_bytes: int = 16 * 1024 * 1024,
-              interpret: bool = True) -> PaddedCOO:
+              vmem_budget_bytes: int = VMEM_BUDGET_BYTES) -> PaddedCOO:
     """Sort-free sliding-hash regime: zero sorts before compaction, one
     stable sort total; output layout is canonical. Runs the shared core as
     a B = 1 batch."""
     cat = concat(mats)
     out = _hash_core(cat.keys[None], cat.vals[None], cat.shape,
-                     vmem_budget_bytes, interpret, cost_model)
+                     vmem_budget_bytes, cost_model)
     return PaddedCOO(keys=out.keys[0], vals=out.vals[0], nnz=out.nnz[0],
                      shape=cat.shape)
 
@@ -520,14 +524,32 @@ def _run_tree(mats: Sequence[PaddedCOO],
     - k>3 (reachable only via a calibrated/custom ``tree_max_k``): the
       balanced tree sums pairs as (a+b)+(c+d), not in stream order, so it
       would break bit-identity — fold left instead (the incremental
-      schedule), which sums every key in stream order. O(k²) data movement
-      is acceptable exactly because this regime only wins at tiny k.
+      schedule), which sums every key in stream order. The fold runs as a
+      loop over a fixed-capacity accumulator (capacity ``sum_i cap_i``,
+      which always holds the distinct keys so far), so XLA compiles one
+      2-way add instead of k-1 differently-shaped ones (at k = 64 and
+      4M inputs, minutes of TPU compile). O(k·sum cap) data movement is
+      acceptable exactly because this regime only wins at tiny k.
     """
     if len(mats) == 1:
         return _alg.spkadd_sorted(mats)
     if len(mats) <= 3:
         return _alg.spkadd_tree(mats)
-    return _alg.spkadd_incremental(mats)
+    total = sum(a.cap for a in mats)
+    rest = [with_capacity(a, max(b.cap for b in mats[1:])) for a in mats[1:]]
+    keys = jnp.stack([a.keys for a in rest])
+    vals = jnp.stack([a.vals for a in rest])
+    shape = mats[0].shape
+
+    def add(i, acc):
+        out = _alg.two_way_add(
+            PaddedCOO(*acc, shape), PaddedCOO(keys[i], vals[i], 0, shape))
+        return out.keys[:total], out.vals[:total], out.nnz
+
+    first = with_capacity(mats[0], total)
+    out = jax.lax.fori_loop(0, len(rest), add,
+                            (first.keys, first.vals, first.nnz))
+    return PaddedCOO(*out, shape)
 
 
 #: Engine-canonical paths: every entry returns the same PaddedCOO bitwise
@@ -665,8 +687,7 @@ def explain_batched_dispatch(stacked_mats: Sequence[PaddedCOO], *,
 
 
 def _run_partitioned_batched(stacked_mats: Sequence[PaddedCOO], regime: str,
-                             vmem_budget_bytes: int = 16 * 1024 * 1024,
-                             interpret: bool = True,
+                             vmem_budget_bytes: int = VMEM_BUDGET_BYTES,
                              cost_model: Optional[Dict[str, float]] = None
                              ) -> PaddedCOO:
     """Batched one-pass partitioned launch: B sorted streams, per-batch step
@@ -676,7 +697,7 @@ def _run_partitioned_batched(stacked_mats: Sequence[PaddedCOO], regime: str,
     keys = jnp.concatenate([a.keys for a in stacked_mats], axis=-1)  # (B, cap)
     vals = jnp.concatenate([a.vals for a in stacked_mats], axis=-1)
     return _partitioned_core(keys, vals, stacked_mats[0].shape, regime,
-                             vmem_budget_bytes, interpret, cost_model)
+                             vmem_budget_bytes, cost_model)
 
 
 def spkadd_batched(stacked_mats: Sequence[PaddedCOO], *,
@@ -705,7 +726,7 @@ def spkadd_batched(stacked_mats: Sequence[PaddedCOO], *,
         keys = jnp.concatenate([a.keys for a in stacked_mats], axis=-1)
         vals = jnp.concatenate([a.vals for a in stacked_mats], axis=-1)
         return _hash_core(keys, vals, stacked_mats[0].shape,
-                          16 * 1024 * 1024, True, cost_model)
+                          VMEM_BUDGET_BYTES, cost_model)
 
     def one(mats):
         return _CANONICAL[effective](mats, cost_model=cost_model) \

@@ -157,14 +157,14 @@ def spkadd_spa_dense(mats: Sequence[PaddedCOO]) -> jax.Array:
 
 
 def spkadd_blocked_spa(mats: Sequence[PaddedCOO], block_rows: int | None = None,
-                       vmem_budget_bytes: int = 16 * 1024 * 1024,
-                       interpret: bool = True) -> PaddedCOO:
+                       vmem_budget_bytes: int = 16 * 1024 * 1024) -> PaddedCOO:
     """Sliding-SPA: the TPU adaptation of the paper's sliding hash (Alg. 7/8).
 
     ``parts = ceil(m*n*bytes / vmem_budget)`` row-blocks; a Pallas kernel
     slides a dense VMEM accumulator tile down the row space while streaming
-    every input nonzero once. See kernels/spa_accum.py. This wrapper handles
-    the PaddedCOO plumbing and re-sparsification.
+    every input nonzero once. See kernels/spa_accum.py (interpreter-only: a
+    CPU reference; the engine's ``blocked_spa`` regime is the TPU path).
+    This wrapper handles the PaddedCOO plumbing and re-sparsification.
     """
     from repro.kernels import ops as kops  # local import: kernels are optional deps
 
@@ -173,21 +173,20 @@ def spkadd_blocked_spa(mats: Sequence[PaddedCOO], block_rows: int | None = None,
     cat = concat(mats)
     flat = kops.spa_accumulate_flat(cat.keys, cat.vals, m=m, n=n,
                                     block_rows=block_rows,
-                                    vmem_budget_bytes=vmem_budget_bytes,
-                                    interpret=interpret)
+                                    vmem_budget_bytes=vmem_budget_bytes)
     return _resparsify_flat(flat, shape, min(cat.cap, m * n))
 
 
 def spkadd_vec(mats: Sequence[PaddedCOO], block_rows: int | None = None,
                vmem_budget_bytes: int = 16 * 1024 * 1024,
-               fold: str = "auto", interpret: bool = True) -> PaddedCOO:
+               fold: str = "auto") -> PaddedCOO:
     """Lane-parallel sliding SpKAdd — the vectorized production variant of
     :func:`spkadd_blocked_spa`.
 
     Same sliding VMEM grid, but the in-tile scatter is replaced by the
-    bitonic sort-fold or the one-hot MXU fold from
-    :mod:`repro.kernels.vec_accum` (``fold="auto"`` picks by tile size):
-    O(distinct-runs) or zero serial stores per chunk instead of O(chunk).
+    serial fold or the one-hot MXU fold from :mod:`repro.kernels.vec_accum`
+    (``fold="auto"`` picks by tile size). Interpreter-only, like
+    :func:`spkadd_blocked_spa`; the engine's ``vec`` regime is the TPU path.
     """
     from repro.kernels import ops as kops
 
@@ -197,23 +196,23 @@ def spkadd_vec(mats: Sequence[PaddedCOO], block_rows: int | None = None,
     flat = kops.vec_accumulate_flat(cat.keys, cat.vals, m=m, n=n,
                                     block_rows=block_rows,
                                     vmem_budget_bytes=vmem_budget_bytes,
-                                    fold=fold, interpret=interpret)
+                                    fold=fold)
     return _resparsify_flat(flat, shape, min(cat.cap, m * n))
 
 
-def spkadd_hash(mats: Sequence[PaddedCOO], interpret: bool = True) -> PaddedCOO:
+def spkadd_hash(mats: Sequence[PaddedCOO]) -> PaddedCOO:
     """Faithful hash-table SpKAdd (paper Alg. 5/6) via the Pallas kernel.
 
-    Correct and bit-faithful to the paper's probing scheme; documented in
-    DESIGN.md as the non-production path on TPU (scalar probe loop).
+    Correct and bit-faithful to the paper's probing scheme; an
+    interpreter-only CPU reference (scalar probe loop). The engine's
+    ``hash`` regime is the TPU path.
     """
     from repro.kernels import ops as kops
 
     shape = mats[0].shape
     cat = concat(mats)
     keys, vals, nnz = kops.hash_accumulate(cat.keys, cat.vals,
-                                           sent=sentinel_key(shape),
-                                           interpret=interpret)
+                                           sent=sentinel_key(shape))
     out = PaddedCOO(keys=keys, vals=vals, nnz=nnz, shape=shape)
     from repro.core.sparse import sort_by_key
     return sort_by_key(out)

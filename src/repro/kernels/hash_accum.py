@@ -6,11 +6,10 @@ probe loop is a ``while_loop`` whose body reads the table ref and whose carry
 decides termination — the canonical Pallas pattern for data-dependent probing.
 
 This kernel exists to reproduce the paper's algorithm *as published*: it is
-bit-faithful, validates in interpret mode, and demonstrates in DESIGN.md why
-scalar probing is the non-production path on TPU (each probe serializes a VMEM
-round-trip; no vector lanes are used). The production accumulator is the
-lane-parallel sliding fold in vec_accum.py (bitonic sort-fold / one-hot MXU
-fold), running on the spa_accum.py sliding grid — see DESIGN.md §4.
+bit-faithful and runs under the Pallas interpreter only — a CPU reference
+that refuses to launch on a TPU (``compat.require_interpreter``). Its scalar
+VMEM stores are exactly what Mosaic rejects; the engine's hash regime is the
+row-probing sliding kernel in ``hash_slide.py``.
 
 Table sizing follows the paper: a power of two strictly greater than the
 worst-case distinct-key count, kept at load factor <= 0.5 so expected probes
@@ -24,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.compat import pallas as pl
+from repro.compat import require_interpreter
 
 HASH_PRIME = 2654435761  # Knuth multiplicative constant
 
@@ -62,7 +62,7 @@ def _probe(table_keys_ref, key: jax.Array, mask: jax.Array, *,
 
     def body(carry):
         h, steps, _ = carry
-        tk = pl.load(table_keys_ref, (h,))
+        tk = table_keys_ref[h]
         done = (tk == -1) | (tk == key)
         h_next = jnp.where(done, h, (h + 1) & mask.astype(jnp.int32))
         return h_next, steps + jnp.int32(1), done
@@ -85,9 +85,8 @@ def _hash_kernel(keys_ref, vals_ref, tkeys_ref, tvals_ref, *, nnz_cap: int,
         @pl.when(k != sent)
         def _do():
             h = _probe(tkeys_ref, k, mask, table_size=table_size)
-            pl.store(tkeys_ref, (h,), k)
-            cur = pl.load(tvals_ref, (h,))
-            pl.store(tvals_ref, (h,), cur + v)
+            tkeys_ref[h] = k
+            tvals_ref[h] = tvals_ref[h] + v
 
         return 0
 
@@ -95,10 +94,10 @@ def _hash_kernel(keys_ref, vals_ref, tkeys_ref, tvals_ref, *, nnz_cap: int,
 
 
 def hash_accumulate_raw(keys: jax.Array, vals: jax.Array, *, sent: int,
-                        table_size: int | None = None,
-                        interpret: bool = True):
+                        table_size: int | None = None):
     """Insert every (key, val) into a VMEM hash table. Returns the raw table
-    (tkeys == -1 marks empty slots)."""
+    (tkeys == -1 marks empty slots). Interpreter-only."""
+    require_interpreter("hash_accumulate_raw")
     if keys.ndim != 1 or keys.shape != vals.shape:
         raise ValueError(f"keys/vals must be matching 1-D streams, got "
                          f"{keys.shape} vs {vals.shape}")
@@ -118,7 +117,7 @@ def hash_accumulate_raw(keys: jax.Array, vals: jax.Array, *, sent: int,
                    pl.BlockSpec((table_size,), lambda: (0,))],
         out_shape=[jax.ShapeDtypeStruct((table_size,), jnp.int32),
                    jax.ShapeDtypeStruct((table_size,), jnp.float32)],
-        interpret=interpret,
+        interpret=True,
     )(keys, vals.astype(jnp.float32))
     return tkeys, tvals
 
@@ -138,11 +137,11 @@ def _hash_symbolic_kernel(keys_ref, nz_ref, tkeys_ref, *, nnz_cap: int,
         @pl.when(k != sent)
         def _do():
             h = _probe(tkeys_ref, k, mask, table_size=table_size)
-            tk = pl.load(tkeys_ref, (h,))
+            tk = tkeys_ref[h]
 
             @pl.when(tk == -1)
             def _new():
-                pl.store(tkeys_ref, (h,), k)
+                tkeys_ref[h] = k
                 nz_ref[0] = nz_ref[0] + 1
 
         return 0
@@ -151,9 +150,10 @@ def _hash_symbolic_kernel(keys_ref, nz_ref, tkeys_ref, *, nnz_cap: int,
 
 
 def hash_symbolic_raw(keys: jax.Array, *, sent: int,
-                      table_size: int | None = None,
-                      interpret: bool = True) -> jax.Array:
-    """Distinct-key count via the faithful hash symbolic phase."""
+                      table_size: int | None = None) -> jax.Array:
+    """Distinct-key count via the faithful hash symbolic phase.
+    Interpreter-only."""
+    require_interpreter("hash_symbolic_raw")
     cap = keys.shape[0]
     if table_size is None:
         table_size = hash_table_size(cap + 1)
@@ -167,6 +167,6 @@ def hash_symbolic_raw(keys: jax.Array, *, sent: int,
                    pl.BlockSpec((table_size,), lambda: (0,))],
         out_shape=[jax.ShapeDtypeStruct((1,), jnp.int32),
                    jax.ShapeDtypeStruct((table_size,), jnp.int32)],
-        interpret=interpret,
+        interpret=True,
     )(keys)
     return nz[0]

@@ -7,8 +7,9 @@ technique of Nagasaka et al. (KNL SpGEMM). Every other engine regime pays
 ``sparse.stable_argsort`` over the concatenated stream *before* it
 accumulates; this kernel pays **zero sorts before compaction**:
 
-- Linear-probing tables live in VMEM output blocks, one table per
-  (batch, output part). Grid ``(B, parts, num_chunks)`` with the chunk axis
+- Linear-probing tables live in VMEM output blocks, one ``(rows, 128)``
+  table per (batch, output part); a probe reads and rewrites one 128-slot
+  row under a lane mask, so Mosaic never stores a scalar to VMEM. Grid ``(B, parts, num_chunks)`` with the chunk axis
   innermost, so a part's table stays resident while the whole input stream
   slides past it (the revisited-output-block pattern from partition.py).
 - Each nonzero is inserted-or-accumulated **in stream order**: slot values
@@ -43,6 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.compat import pallas as pl
+from repro.compat import pallas_tpu as pltpu
+from repro.kernels import LANES, VMEM_LIMIT_BYTES
 from repro.kernels.hash_accum import HASH_PRIME, hash_table_size
 
 __all__ = [
@@ -55,55 +58,65 @@ __all__ = [
 def _probe_insert(tkeys_ref, tvals_ref, key, val, *, table_size: int):
     """Insert-or-accumulate one (key, val) into the part's VMEM table.
 
-    The probe ``while_loop`` carries a step counter bounded by
-    ``table_size`` (spkaddlint SPK107); at load factor <= 0.5 the chain
-    terminates on an empty-or-match slot long before the bound.
+    The table is ``(rows, width)``: slot ``h`` sits at row ``h // width``,
+    lane ``h % width``. Linear probing scans a whole row per step: the
+    first lane at or after the probe position whose key is empty or equal
+    to ``key`` is the slot — the same slot a one-slot-at-a-time probe would
+    reach — and the row is rewritten under a one-lane mask. The probe
+    ``while_loop`` is bounded by ``rows + 1`` row steps (spkaddlint SPK107):
+    at load factor <= 0.5 it ends long before.
     """
-    mask = jnp.uint32(table_size - 1)
-    prime = jnp.asarray(HASH_PRIME, jnp.uint32)
-    h0 = ((key.astype(jnp.uint32) * prime) & mask).astype(jnp.int32)
+    rows, width = tkeys_ref.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    h0 = ((key.astype(jnp.uint32) * jnp.uint32(HASH_PRIME))
+          & jnp.uint32(table_size - 1)).astype(jnp.int32)
 
     def cond(carry):
-        _, steps, done = carry
-        return jnp.logical_not(done) & (steps < table_size)
+        _, _, steps, done = carry
+        return jnp.logical_not(done) & (steps <= rows)
 
     def body(carry):
-        h, steps, _ = carry
-        tk = pl.load(tkeys_ref, (h,))
-        done = (tk == -1) | (tk == key)
-        h_next = jnp.where(done, h, (h + 1) & jnp.int32(table_size - 1))
-        return h_next, steps + jnp.int32(1), done
+        r, start, steps, _ = carry
+        tk = tkeys_ref[pl.ds(r, 1), :]
+        hit = (lane >= start) & ((tk == -1) | (tk == key))
+        first = jnp.min(jnp.where(hit, lane, width))
+        found = first < width
 
-    h, _, _ = jax.lax.while_loop(cond, body, (h0, jnp.int32(0), False))
-    pl.store(tkeys_ref, (h,), key)
-    cur = pl.load(tvals_ref, (h,))
-    pl.store(tvals_ref, (h,), cur + val)
+        @pl.when(found)
+        def _write():
+            at = lane == first
+            tv = tvals_ref[pl.ds(r, 1), :]
+            tkeys_ref[pl.ds(r, 1), :] = jnp.where(at, key, tk)
+            tvals_ref[pl.ds(r, 1), :] = jnp.where(at, tv + val, tv)
+
+        return (r + 1) % rows, jnp.int32(0), steps + 1, found
+
+    jax.lax.while_loop(cond, body,
+                       (h0 // width, h0 % width, jnp.int32(0), False))
 
 
 def _slide_kernel(keys_ref, vals_ref, tkeys_ref, tvals_ref, *, mn: int,
-                  table_size: int, part_span: int, chunk: int):
+                  part_span: int, chunk: int):
     p = pl.program_id(1)
     c = pl.program_id(2)
+    table_size = tkeys_ref.shape[0] * tkeys_ref.shape[1]
 
     @pl.when(c == 0)
     def _init():
-        tkeys_ref[...] = jnp.full((table_size,), -1, jnp.int32)
-        tvals_ref[...] = jnp.zeros((table_size,), jnp.float32)
+        tkeys_ref[...] = jnp.full(tkeys_ref.shape, -1, jnp.int32)
+        tvals_ref[...] = jnp.zeros(tvals_ref.shape, jnp.float32)
 
-    keys = keys_ref[0]
-    vals = vals_ref[0]
     lo = p * part_span
 
-    def insert(e, _):
-        k = keys[e]
-        v = vals[e]
-        in_part = (k >= lo) & (k - lo < part_span) & (k < mn)
+    def insert(e, carry):
+        k = keys_ref[e]
 
-        @pl.when(in_part)
+        @pl.when((k >= lo) & (k - lo < part_span) & (k < mn))
         def _do():
-            _probe_insert(tkeys_ref, tvals_ref, k, v, table_size=table_size)
+            _probe_insert(tkeys_ref, tvals_ref, k, vals_ref[e],
+                          table_size=table_size)
 
-        return 0
+        return carry
 
     jax.lax.fori_loop(0, chunk, insert, 0)
 
@@ -118,7 +131,8 @@ def hash_slide_raw(keys: jax.Array, vals: jax.Array, *, mn: int,
     ``(B, parts * table_size)`` (int32 keys, -1 = empty; f32 values), with
     part ``p`` owning keys in ``[p * part_span, (p + 1) * part_span)`` —
     concatenated part tables are key-range ordered, so one final stable
-    sort yields the canonical layout.
+    sort yields the canonical layout. Stream chunks are read as scalars
+    from SMEM; each part's table is a ``(rows, 128)`` VMEM block.
     """
     if keys.ndim != 2 or keys.shape != vals.shape:
         raise ValueError(f"keys/vals must be matching (B, cap) streams, got "
@@ -137,24 +151,29 @@ def hash_slide_raw(keys: jax.Array, vals: jax.Array, *, mn: int,
         raise ValueError(f"parts {parts} x span {part_span} must cover "
                          f"key space {mn}")
     num_chunks = cap // chunk
+    width = min(table_size, LANES)
+    rows = table_size // width
 
-    kernel = functools.partial(_slide_kernel, mn=mn, table_size=table_size,
-                               part_span=part_span, chunk=chunk)
+    stream = pl.BlockSpec((None, None, chunk), lambda b, p, c: (b, 0, c),
+                          memory_space=pltpu.SMEM)
+    table = pl.BlockSpec((None, rows, width),
+                         lambda b, p, c: (b * parts + p, 0, 0))
+    kernel = functools.partial(_slide_kernel, mn=mn, part_span=part_span,
+                               chunk=chunk)
     tkeys, tvals = pl.pallas_call(
         kernel,
         grid=(B, parts, num_chunks),
-        in_specs=[pl.BlockSpec((1, chunk), lambda b, p, c: (b, c)),
-                  pl.BlockSpec((1, chunk), lambda b, p, c: (b, c))],
-        out_specs=[
-            pl.BlockSpec((table_size,), lambda b, p, c: (b * parts + p,)),
-            pl.BlockSpec((table_size,), lambda b, p, c: (b * parts + p,)),
-        ],
+        in_specs=[stream, stream],
+        out_specs=[table, table],
         out_shape=[
-            jax.ShapeDtypeStruct((B * parts * table_size,), jnp.int32),
-            jax.ShapeDtypeStruct((B * parts * table_size,), jnp.float32),
+            jax.ShapeDtypeStruct((B * parts, rows, width), jnp.int32),
+            jax.ShapeDtypeStruct((B * parts, rows, width), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(keys.astype(jnp.int32), vals.astype(jnp.float32))
+    )(keys.astype(jnp.int32).reshape(B, 1, cap),
+      vals.astype(jnp.float32).reshape(B, 1, cap))
     return (tkeys.reshape(B, parts * table_size),
             tvals.reshape(B, parts * table_size))
 

@@ -13,8 +13,10 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.compat import interpret_kernels
 from repro.core.sparse import next_pow2 as _next_pow2
 from repro.core.sparse import stable_argsort as _stable_argsort
+from repro.kernels import VMEM_BUDGET_BYTES
 from repro.kernels import hash_accum as _hash
 from repro.kernels import spa_accum as _spa
 from repro.kernels import vec_accum as _vec
@@ -45,13 +47,11 @@ def choose_block_rows(m: int, n: int, vmem_budget_bytes: int,
 
 
 @functools.partial(jax.jit, static_argnames=("m", "n", "block_rows",
-                                             "vmem_budget_bytes", "chunk",
-                                             "interpret"))
+                                             "vmem_budget_bytes", "chunk"))
 def spa_accumulate(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
                    block_rows: int | None = None,
                    vmem_budget_bytes: int = 16 * 1024 * 1024,
-                   chunk: int = _spa.DEFAULT_CHUNK,
-                   interpret: bool = True) -> jax.Array:
+                   chunk: int = _spa.DEFAULT_CHUNK) -> jax.Array:
     """Sliding blocked-SPA accumulate -> dense (m, n) f32.
 
     Pads the input stream to a chunk multiple (sentinel keys) and the row
@@ -68,15 +68,13 @@ def spa_accumulate(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
     vals_p = jnp.zeros((cap_pad,), jnp.float32).at[:cap].set(
         jnp.where(keys < m * n, vals.astype(jnp.float32), 0.0))
     return _spa.spa_accumulate_raw(keys_p, vals_p, m=m, n=n,
-                                   block_rows=block_rows, chunk=chunk,
-                                   interpret=interpret)
+                                   block_rows=block_rows, chunk=chunk)
 
 
 def spa_accumulate_flat(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
                         block_rows: int | None = None,
                         vmem_budget_bytes: int = 16 * 1024 * 1024,
-                        chunk: int = _spa.DEFAULT_CHUNK,
-                        interpret: bool = True) -> jax.Array:
+                        chunk: int = _spa.DEFAULT_CHUNK) -> jax.Array:
     """Sliding blocked-SPA accumulate -> flat (m*n,) f32 in *key order*
     (col-major), so ``flat[key]`` is the accumulated value of ``key``.
 
@@ -84,8 +82,7 @@ def spa_accumulate_flat(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
     straight out of the accumulator without a dense (m, n) detour.
     """
     dense = spa_accumulate(keys, vals, m=m, n=n, block_rows=block_rows,
-                           vmem_budget_bytes=vmem_budget_bytes, chunk=chunk,
-                           interpret=interpret)
+                           vmem_budget_bytes=vmem_budget_bytes, chunk=chunk)
     return dense.T.reshape(-1)
 
 
@@ -100,16 +97,16 @@ def fold_working_set_bytes(fold: str, *, tile_elems: int, chunk: int) -> int:
     engine, and by the static VMEM-budget rule (``repro.analysis.vmem``), so
     the analyzer proves exactly the budget the runtime enforces.
 
-    Counts the f32 output tile, the double-buffered int32-key/f32-val input
-    blocks (two in-flight ``(chunk,)`` pairs, 8 B per element), and — for the
-    one-hot fold only — the materialized ``(chunk, tile_elems)`` f32 one-hot
-    plus its int32 iota (8 B per cell). The sort-fold's bitonic network
-    permutes the resident chunk in place (vector registers), so it adds no
-    VMEM term.
+    Counts both pipeline buffers of the f32 output tile (Pallas
+    double-buffers output blocks too), the double-buffered int32-key/f32-val
+    input blocks (two in-flight ``(chunk,)`` pairs, 8 B per element), and —
+    for the one-hot fold only — its per-round ``(rows, chunk)`` value matrix
+    and ``(128, chunk)`` lane one-hot plus their masks (8 B per cell). The
+    scalar folds keep one tile row in registers, so they add no VMEM term.
     """
-    out_tile = tile_elems * 4
+    out_tile = 2 * tile_elems * 4
     inputs = 2 * chunk * 8
-    inter = chunk * tile_elems * 8 if fold == "onehot" else 0
+    inter = chunk * (tile_elems // 128 + 128) * 8 if fold == "onehot" else 0
     return out_tile + inputs + inter
 
 
@@ -130,23 +127,22 @@ def vec_launch_geometry(cap: int, *, m: int, n: int,
 
 @functools.partial(jax.jit, static_argnames=("m", "n", "fold", "block_rows",
                                              "vmem_budget_bytes", "chunk",
-                                             "onehot_max_block_elems",
-                                             "interpret"))
+                                             "onehot_max_block_elems"))
 def vec_accumulate(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
                    fold: str = "auto", block_rows: int | None = None,
                    vmem_budget_bytes: int = 16 * 1024 * 1024,
                    chunk: int | None = None,
-                   onehot_max_block_elems: int = DEFAULT_ONEHOT_MAX_BLOCK_ELEMS,
-                   interpret: bool = True) -> jax.Array:
+                   onehot_max_block_elems: int = DEFAULT_ONEHOT_MAX_BLOCK_ELEMS
+                   ) -> jax.Array:
     """Lane-parallel sliding accumulate -> dense (m, n) f32.
 
     Same sliding grid as :func:`spa_accumulate`, but the in-tile fold is one
-    of the vectorized paths from :mod:`repro.kernels.vec_accum`:
-    ``fold="sort"`` (bitonic sort-fold, O(distinct-runs) serial stores) or
-    ``fold="onehot"`` (one-hot MXU fold, zero serial stores).
+    of the folds from :mod:`repro.kernels.vec_accum`: ``fold="serial"`` (one
+    tile-row store per element) or ``fold="onehot"`` (one-hot MXU fold,
+    zero serial stores).
     ``fold="auto"`` picks ``onehot`` when the tile has at most
-    ``onehot_max_block_elems`` elements (the matmul's O(chunk·block_elems)
-    FLOPs stay cheap) and ``sort`` otherwise.
+    ``onehot_max_block_elems`` elements (the matmul's FLOPs stay cheap) and
+    ``serial`` otherwise.
 
     The stream is **pre-sorted by key (stable)** before launch. That makes
     the fold bit-identical to the canonical ``compress_plan`` contract
@@ -167,22 +163,20 @@ def vec_accumulate(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
         cap, m=m, n=n, block_rows=block_rows,
         vmem_budget_bytes=vmem_budget_bytes, chunk=chunk)
     if fold == "auto":
-        # the one-hot fold materializes a (chunk, block_elems) f32 one-hot
-        # plus an int32 iota of the same shape — the WHOLE step working set
-        # (tile + double-buffered inputs + those intermediates) must fit the
-        # VMEM budget, or the "small tile" regime is a lie on real hardware
+        # the WHOLE step working set (both tile buffers, double-buffered
+        # inputs, the one-hot intermediates) must fit the VMEM budget
         onehot_ws = fold_working_set_bytes(
             "onehot", tile_elems=block_rows * n, chunk=chunk)
         fold = "onehot" if (block_rows * n <= onehot_max_block_elems
                             and onehot_ws <= vmem_budget_bytes) \
-            else "sort"
+            else "serial"
 
     cap_pad = _round_up(max(cap, 1), chunk)
     keys_p = jnp.full((cap_pad,), sent, jnp.int32).at[:cap].set(keys_s)
     vals_p = jnp.zeros((cap_pad,), jnp.float32).at[:cap].set(vals_s)
     return _spa.spa_accumulate_raw(keys_p, vals_p, m=m, n=n,
                                    block_rows=block_rows, chunk=chunk,
-                                   fold=fold, interpret=interpret)
+                                   fold=fold)
 
 
 def vec_accumulate_flat(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
@@ -198,7 +192,7 @@ def vec_store_counts(keys, *, m: int, n: int,
                      block_rows: int | None = None,
                      vmem_budget_bytes: int = 16 * 1024 * 1024,
                      chunk: int | None = None) -> dict:
-    """Host-side serial-store counts (serial vs sort-fold vs one-hot) for
+    """Host-side serial-store counts (serial vs one-hot) for
     the launch geometry :func:`vec_accumulate` would use on this stream."""
     block_rows, chunk = vec_launch_geometry(
         len(keys), m=m, n=n, block_rows=block_rows,
@@ -206,7 +200,6 @@ def vec_store_counts(keys, *, m: int, n: int,
     counts = _vec.chunk_store_counts(keys, m=m, n=n, block_rows=block_rows,
                                      chunk=chunk)
     obs.gauge("kernels.vec.stores.serial").set(counts["serial"])
-    obs.gauge("kernels.vec.stores.sort_fold").set(counts["sort_fold"])
     obs.gauge("kernels.vec.stores.onehot_fold").set(counts["onehot_fold"])
     return counts
 
@@ -230,7 +223,7 @@ class PartitionGeometry(_t.NamedTuple):
 
 def partitioned_launch_geometry(cap: int, *, m: int, n: int,
                                 part_elems: int | None = None,
-                                vmem_budget_bytes: int = 16 * 1024 * 1024,
+                                vmem_budget_bytes: int = VMEM_BUDGET_BYTES,
                                 chunk: int | None = None) -> PartitionGeometry:
     """Geometry the partitioned launch uses for a ``cap``-long stream.
 
@@ -238,14 +231,14 @@ def partitioned_launch_geometry(cap: int, *, m: int, n: int,
     double-buffered input blocks (two in-flight ``(chunk,)`` key/value
     pairs, 8 bytes per element) get at most half of
     ``vmem_budget_bytes`` — ``chunk`` halves (staying a power of two,
-    floored at 8) until they fit — and ``part_elems`` is the largest lane
-    multiple whose f32 tile fits the remainder, rounded **down** and
-    floored at the lane multiple (same discipline as
-    :func:`choose_block_rows`; the two floors are the only sanctioned
-    excess, for sub-minimal budgets), then clipped to the accumulator
-    size. Parts are key-aligned ranges, which is what lets the canonical
-    sort double as the partition sort (``sparse.plan_and_partition``).
-    Explicit ``chunk``/``part_elems`` overrides are taken as-is.
+    floored at 8) until they fit — and ``part_elems`` is the largest multiple
+    of one ``(8, 128)`` f32 tile whose two pipeline buffers fit the
+    remainder, rounded **down** and floored at one lane row (same discipline
+    as :func:`choose_block_rows`; the two floors are the only sanctioned
+    excess, for sub-minimal budgets), then clipped to the accumulator size.
+    Parts are key-aligned ranges, which is what lets the canonical sort
+    double as the partition sort (``sparse.plan_and_partition``). Explicit
+    ``chunk``/``part_elems`` overrides are taken as-is.
     """
     from repro.kernels import partition as _part
 
@@ -256,9 +249,10 @@ def partitioned_launch_geometry(cap: int, *, m: int, n: int,
             chunk //= 2  # input double-buffers get at most half the budget
     if part_elems is None:
         input_bytes = 2 * chunk * 8  # double-buffered int32 keys + f32 vals
-        budget_elems = max(1, (vmem_budget_bytes - input_bytes) // 4)
-        part_elems = max(_part.LANE_MULT,
-                         _round_down(budget_elems, _part.LANE_MULT))
+        budget_elems = max(1, (vmem_budget_bytes - input_bytes) // 8)
+        step = 8 * _part.LANE_MULT if budget_elems >= 8 * _part.LANE_MULT \
+            else _part.LANE_MULT
+        part_elems = max(_part.LANE_MULT, _round_down(budget_elems, step))
         part_elems = min(part_elems, _round_up(mn, _part.LANE_MULT))
     parts = max(1, (mn + part_elems - 1) // part_elems)
     cap_pad = _round_up(max(cap, 1), chunk)
@@ -276,21 +270,23 @@ def partitioned_launch_geometry(cap: int, *, m: int, n: int,
 
 
 @functools.partial(jax.jit, static_argnames=("m", "n", "part_elems", "parts",
-                                             "chunk", "fold", "interpret"))
-def partitioned_accumulate_flat(keys_sorted: jax.Array, vals_sorted: jax.Array,
-                                chunk_id: jax.Array, part_id: jax.Array, *,
-                                m: int, n: int, part_elems: int, parts: int,
-                                chunk: int, fold: str = "sort",
-                                interpret: bool = True) -> jax.Array:
-    """One-pass partitioned accumulate -> flat f32 in key order (col-major),
-    so ``flat[..., key]`` is the accumulated value of ``key``.
+                                             "chunk", "fold"))
+def partitioned_accumulate(keys_sorted: jax.Array, vals_sorted: jax.Array,
+                           chunk_id: jax.Array, part_id: jax.Array, *,
+                           m: int, n: int, part_elems: int, parts: int,
+                           chunk: int, fold: str = "serial") -> jax.Array:
+    """One-pass partitioned accumulate -> the key-ordered accumulator as
+    rows: ``acc[..., key // width, key % width]`` is the accumulated value
+    of ``key`` (read it with :func:`take_keys`).
 
     Unlike :func:`vec_accumulate_flat` this wrapper does **not** sort: it
     takes the canonically sorted, sentinel-padded stream and the step
     tables straight from ``sparse.plan_and_partition`` — the engine's one
     stable sort is shared, not repeated. Accepts ``(cap_pad,)`` streams or
     ``(B, cap_pad)`` batched stacks (with ``(B, max_steps)`` tables); the
-    batch dimension becomes the leading grid dimension of one launch.
+    batch dimension becomes the leading grid dimension of one launch. The
+    rows form is kept (not flattened) because flattening a batched
+    accumulator is a relayout copy of the whole array on the TPU.
     """
     from repro.kernels import partition as _part
 
@@ -300,16 +296,25 @@ def partitioned_accumulate_flat(keys_sorted: jax.Array, vals_sorted: jax.Array,
         vals_sorted = vals_sorted[None]
         chunk_id = chunk_id[None]
         part_id = part_id[None]
-    flat = _part.partitioned_accumulate_raw(
+    acc = _part.partitioned_accumulate_raw(
         keys_sorted.astype(jnp.int32), vals_sorted.astype(jnp.float32),
         chunk_id, part_id, mn=m * n, part_elems=part_elems, parts=parts,
-        chunk=chunk, fold=fold, interpret=interpret)[:, :m * n]
-    return flat[0] if squeeze else flat
+        chunk=chunk, fold=fold, interpret=interpret_kernels())
+    return acc[0] if squeeze else acc
 
 
-@functools.partial(jax.jit, static_argnames=("sent", "table_size", "interpret"))
+def take_keys(acc: jax.Array, keys: jax.Array) -> jax.Array:
+    """Gather ``keys`` out of an accumulator: a flat ``(L,)`` array, or the
+    ``(rows, width)`` form :func:`partitioned_accumulate` returns."""
+    if acc.ndim == 1:
+        return acc[keys]
+    width = acc.shape[-1]
+    return acc[keys // width, keys % width]
+
+
+@functools.partial(jax.jit, static_argnames=("sent", "table_size"))
 def hash_accumulate(keys: jax.Array, vals: jax.Array, *, sent: int,
-                    table_size: int | None = None, interpret: bool = True):
+                    table_size: int | None = None):
     """Faithful hash SpKAdd -> (keys[cap], vals[cap], nnz), key-compacted.
 
     The raw VMEM table is compacted by moving occupied slots to the front
@@ -317,8 +322,7 @@ def hash_accumulate(keys: jax.Array, vals: jax.Array, *, sent: int,
     """
     cap = keys.shape[0]
     tkeys, tvals = _hash.hash_accumulate_raw(keys, vals, sent=sent,
-                                             table_size=table_size,
-                                             interpret=interpret)
+                                             table_size=table_size)
     occupied = tkeys != -1
     order = _stable_argsort(jnp.logical_not(occupied))
     ck = jnp.where(occupied[order], tkeys[order], sent)[:cap]
@@ -327,12 +331,11 @@ def hash_accumulate(keys: jax.Array, vals: jax.Array, *, sent: int,
     return ck.astype(jnp.int32), cv, nnz
 
 
-@functools.partial(jax.jit, static_argnames=("sent", "table_size", "interpret"))
-def hash_symbolic(keys: jax.Array, *, sent: int, table_size: int | None = None,
-                  interpret: bool = True) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("sent", "table_size"))
+def hash_symbolic(keys: jax.Array, *, sent: int,
+                  table_size: int | None = None) -> jax.Array:
     """Faithful symbolic phase (distinct-key count)."""
-    return _hash.hash_symbolic_raw(keys, sent=sent, table_size=table_size,
-                                   interpret=interpret)
+    return _hash.hash_symbolic_raw(keys, sent=sent, table_size=table_size)
 
 
 # ---------------------------------------------------------------------------
@@ -353,14 +356,15 @@ class HashGeometry(_t.NamedTuple):
 
 
 def hash_launch_geometry(cap: int, *, m: int, n: int,
-                         vmem_budget_bytes: int = 16 * 1024 * 1024,
+                         vmem_budget_bytes: int = VMEM_BUDGET_BYTES,
                          chunk: int | None = None) -> HashGeometry:
     """Geometry the sliding-hash launch uses for a ``cap``-long stream.
 
     Same budgeting discipline as :func:`partitioned_launch_geometry`: the
     double-buffered input blocks get at most half the budget (``chunk``
-    halves, staying a power of two, floored at 8), then the table takes the
-    remainder at 8 bytes per slot (int32 key + f32 value). If one table
+    halves, staying a power of two, floored at 8), then the table's two
+    pipeline buffers take the remainder at 16 bytes per slot (int32 key +
+    f32 value, double-buffered). If one table
     sized by ``hash_accum.hash_table_size`` for the whole stream fits,
     ``parts == 1`` and every chunk is DMA'd exactly once — the paper's
     I/O lower bound with **no pre-sort**. Otherwise the table is the
@@ -376,10 +380,10 @@ def hash_launch_geometry(cap: int, *, m: int, n: int,
             chunk //= 2  # input double-buffers get at most half the budget
     input_bytes = 2 * chunk * 8
     full_table = _hash.hash_table_size(min(max(cap, 1), mn))
-    if full_table * 8 + input_bytes <= vmem_budget_bytes:
+    if 2 * full_table * 8 + input_bytes <= vmem_budget_bytes:
         table_size, part_span, parts = full_table, mn, 1
     else:
-        budget_slots = max(1, (vmem_budget_bytes - input_bytes) // 8)
+        budget_slots = max(1, (vmem_budget_bytes - input_bytes) // 16)
         table_size = max(128, _next_pow2(budget_slots + 1) // 2)
         part_span = table_size // 2
         parts = (mn + part_span - 1) // part_span
@@ -396,11 +400,9 @@ def hash_launch_geometry(cap: int, *, m: int, n: int,
 
 
 @functools.partial(jax.jit, static_argnames=("m", "n", "table_size",
-                                             "part_span", "parts", "chunk",
-                                             "interpret"))
+                                             "part_span", "parts", "chunk"))
 def hash_slide_tables(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
-                      table_size: int, part_span: int, parts: int, chunk: int,
-                      interpret: bool = True):
+                      table_size: int, part_span: int, parts: int, chunk: int):
     """Sort-free sliding-hash accumulate -> raw part tables.
 
     Takes ``(B, cap)`` streams in **arbitrary order** (no pre-sort — that
@@ -422,4 +424,5 @@ def hash_slide_tables(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
     return _hslide.hash_slide_raw(keys_p, vals_p, mn=m * n,
                                   table_size=table_size,
                                   part_span=part_span, parts=parts,
-                                  chunk=chunk, interpret=interpret)
+                                  chunk=chunk,
+                                  interpret=interpret_kernels())

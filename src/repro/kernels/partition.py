@@ -35,11 +35,13 @@ is what lets ``engine.spkadd_batched`` keep a `vec` selection on the Pallas
 path instead of silently downgrading to the dense-SPA scatter.
 
 In-tile folds are shared with the legacy grid (``vec_accum.FOLD_FNS``:
-``serial`` / ``sort`` / ``onehot``); tiles are flat ``(1, part_elems)``
-slices of the col-major dense accumulator, so the kernel's output *is* the
-flat key-ordered array the engine's canonical gather consumes — no
-transpose epilogue. Bit-identity with the canonical contract holds because
-the stream is in stable key order: each key's duplicates are contiguous, in
+``serial`` / ``onehot``). A part's tile is ``(part_elems //
+width, width)`` with ``width = 128`` lanes (the geometry keeps
+``part_elems`` a lane multiple), so the kernel's output, read as
+``(B, parts * rows, width)``, *is* the key-ordered accumulator:
+``acc[b, key // width, key % width]`` is key's value — no transpose
+epilogue. Bit-identity with the canonical contract holds because the
+stream is in stable key order: each key's duplicates are contiguous, in
 stream order, and span only consecutive steps of one part (DESIGN.md §4).
 """
 from __future__ import annotations
@@ -52,19 +54,26 @@ import numpy as np
 
 from repro.compat import pallas as pl
 from repro.compat import pallas_tpu as pltpu
+from repro.kernels import LANES, VMEM_LIMIT_BYTES
 from repro.kernels import vec_accum as _vec
 
 
-#: Sublane/lane multiple for flat f32 accumulator tiles.
-LANE_MULT = 128
+#: lane multiple the geometry keeps ``part_elems`` at.
+LANE_MULT = LANES
+
+
+def tile_width(elems: int) -> int:
+    """Lane width of a ``elems``-slot tile: full 128-lane rows when
+    ``elems`` is a lane multiple, else one row (small test geometries)."""
+    return LANES if elems % LANES == 0 else elems
 
 
 def _partitioned_kernel(chunk_ref, part_ref, keys_ref, vals_ref, out_ref, *,
                         mn: int, part_elems: int, parts: int, fold: str):
     """Grid step (b, t): fold chunk ``chunk_id[b, t]`` into the tile of part
     ``part_id[b, t]``. The tile is zeroed when the (batch, part) block first
-    becomes resident; masked elements (other parts' keys in a boundary
-    chunk, sentinels, padded steps) contribute nothing."""
+    becomes resident; keys outside the part (other parts' keys in a boundary
+    chunk, sentinels, every key of a padded step) contribute nothing."""
     b = pl.program_id(0)
     t = pl.program_id(1)
     p_raw = part_ref[b, t]
@@ -75,27 +84,25 @@ def _partitioned_kernel(chunk_ref, part_ref, keys_ref, vals_ref, out_ref, *,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    keys = keys_ref[0]
-    vals = vals_ref[0]
     lo = p * part_elems
-    valid = ((keys >= lo) & (keys < lo + part_elems) & (keys < mn)
-             & (p_raw < parts))
-    slot = jnp.where(valid, keys - lo, part_elems)
-    _vec.apply_fold(fold, slot, vals, valid, out_ref, n_cols=part_elems)
+    hi = jnp.where(p_raw < parts, jnp.minimum(lo + part_elems, mn), lo)
+    _vec.apply_fold(fold, keys_ref, vals_ref, out_ref,
+                    lambda k: ((k >= lo) & (k < hi), k - lo))
 
 
 def partitioned_accumulate_raw(keys: jax.Array, vals: jax.Array,
                                chunk_id: jax.Array, part_id: jax.Array, *,
                                mn: int, part_elems: int, parts: int,
-                               chunk: int, fold: str = "sort",
+                               chunk: int, fold: str = "serial",
                                interpret: bool = True) -> jax.Array:
-    """One-pass partitioned scatter-accumulate -> flat ``(B, parts*part_elems)``.
+    """One-pass partitioned scatter-accumulate -> ``(B, parts * rows, width)``.
 
     ``keys``/``vals`` are ``(B, cap_pad)`` **sorted** streams (ascending,
     sentinel-padded to a chunk multiple); ``chunk_id``/``part_id`` are the
-    ``(B, max_steps)`` step tables from ``sparse.partition_steps``. The
-    result's leading ``mn`` elements per batch are the col-major dense
-    accumulator in key order (``flat[b, key]`` = accumulated value).
+    ``(B, max_steps)`` step tables from ``sparse.partition_steps``. In the
+    result, ``acc[b, key // width, key % width]`` is the accumulated value
+    of ``key`` (``width = tile_width(part_elems)``). The serial fold reads
+    its chunks from SMEM, the one-hot fold from VMEM.
     """
     if keys.ndim != 2 or keys.shape != vals.shape:
         raise ValueError(f"keys/vals must be matching 2-D streams, got "
@@ -106,33 +113,40 @@ def partitioned_accumulate_raw(keys: jax.Array, vals: jax.Array,
         raise ValueError("pad streams to a chunk multiple")
     if fold not in _vec.FOLDS:
         raise ValueError(f"unknown fold {fold!r}; one of {_vec.FOLDS}")
-    if fold != "serial" and chunk & (chunk - 1) != 0:
-        raise ValueError(
-            "vectorized folds need a power-of-two chunk (bitonic network)")
     B, cap_pad = keys.shape
     max_steps = chunk_id.shape[1]
+    width = tile_width(part_elems)
+    rows = part_elems // width
 
+    def stream_index(b, t, c_ref, p_ref):
+        return b, 0, c_ref[b, t]
+
+    if fold in _vec.SCALAR_FOLDS:
+        stream = pl.BlockSpec((None, None, chunk), stream_index,
+                              memory_space=pltpu.SMEM)
+    else:
+        stream = pl.BlockSpec((None, 1, chunk), stream_index)
     kernel = functools.partial(_partitioned_kernel, mn=mn,
                                part_elems=part_elems, parts=parts, fold=fold)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, max_steps),
-        in_specs=[
-            pl.BlockSpec((1, chunk), lambda b, t, c_ref, p_ref: (b, c_ref[b, t])),
-            pl.BlockSpec((1, chunk), lambda b, t, c_ref, p_ref: (b, c_ref[b, t])),
-        ],
+        in_specs=[stream, stream],
         out_specs=pl.BlockSpec(
-            (1, part_elems),
+            (None, rows, width),
             lambda b, t, c_ref, p_ref: (
-                b * parts + jnp.minimum(p_ref[b, t], parts - 1), 0)),
+                b * parts + jnp.minimum(p_ref[b, t], parts - 1), 0, 0)),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * parts, part_elems), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B * parts, rows, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(chunk_id, part_id, keys, vals)
-    return out.reshape(B, parts * part_elems)
+    )(chunk_id, part_id, keys.reshape(B, 1, cap_pad),
+      vals.reshape(B, 1, cap_pad))
+    return out.reshape(B, parts * rows, width)
 
 
 # ---------------------------------------------------------------------------

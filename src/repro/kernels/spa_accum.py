@@ -23,20 +23,16 @@ the paper's cache discipline with M := VMEM, minus its I/O discipline.
 Keys are CSC-linearized (``key = col*m + row``); the sentinel ``m*n`` (or
 anything >= m*n) marks padding and is dropped in-kernel.
 
-The **in-tile fold is pluggable** (``fold=`` launch parameter):
+The **in-tile fold is pluggable** (``fold=`` launch parameter): the
+``serial`` / ``sort`` / ``onehot`` folds of :mod:`repro.kernels.vec_accum`,
+the same ones the partitioned grid runs, here over a ``(block_rows, n)``
+tile (slot ``(row - row_lo) * n + col``). ``kernels/ops.vec_accumulate``
+pre-sorts the stream, which makes every fold bit-identical to the canonical
+``compress_plan`` contract.
 
-- ``"serial"`` — the original ``fori_loop`` of one dynamic store per input
-  element. O(chunk) dependent stores; kept as the fidelity baseline and for
-  streams that are not pre-sorted.
-- ``"sort"`` / ``"onehot"`` — the lane-parallel folds from
-  :mod:`repro.kernels.vec_accum` (bitonic sort + stream-order run fold;
-  stores either compacted to O(distinct runs) or expressed as a one-hot MXU
-  matmul). These are the production paths — see DESIGN.md §4 for the
-  FLOP/byte trade-off and ``kernels/ops.vec_accumulate`` for the public
-  wrapper (which pre-sorts the stream so the fold is bit-identical to the
-  canonical ``compress_plan`` contract).
-
-Interpret mode validates all three folds bit-exactly against kernels/ref.py.
+This grid runs under the Pallas interpreter only: a CPU reference that
+refuses to launch on a TPU (``compat.require_interpreter``). Interpret mode
+validates both folds bit-exactly against kernels/ref.py.
 """
 from __future__ import annotations
 
@@ -46,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.compat import pallas as pl
+from repro.compat import require_interpreter
 from repro.kernels import vec_accum as _vec
 
 
@@ -53,7 +50,7 @@ DEFAULT_CHUNK = 1024
 
 
 def _spa_kernel(keys_ref, vals_ref, out_ref, *, m: int, n: int,
-                block_rows: int, chunk: int, fold: str):
+                block_rows: int, fold: str):
     """``m`` is the TRUE row count (keys are col*m+row); the grid may cover a
     padded row space (parts*block_rows >= m) — trailing rows just stay 0."""
     part = pl.program_id(0)
@@ -64,29 +61,29 @@ def _spa_kernel(keys_ref, vals_ref, out_ref, *, m: int, n: int,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     row_lo = part * block_rows
-    keys = keys_ref[...]
-    vals = vals_ref[...]
-    rows = keys % m
-    cols = keys // m
-    valid = (keys < m * n) & (rows >= row_lo) & (rows < row_lo + block_rows)
-    # local row-major slot into the (block_rows, n) tile
-    slot = jnp.where(valid, (rows - row_lo) * n + cols, block_rows * n)
-    _vec.apply_fold(fold, slot, vals, valid, out_ref, n_cols=n)
+
+    def locate(keys):
+        rows = keys % m
+        valid = ((keys < m * n) & (rows >= row_lo)
+                 & (rows < row_lo + block_rows))
+        return valid, (rows - row_lo) * n + keys // m
+
+    _vec.apply_fold(fold, keys_ref, vals_ref, out_ref, locate)
 
 
 def spa_accumulate_raw(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
                        block_rows: int, chunk: int = DEFAULT_CHUNK,
-                       fold: str = "serial",
-                       interpret: bool = True) -> jax.Array:
+                       fold: str = "serial") -> jax.Array:
     """Scatter-accumulate (key, val) streams into a dense (m, n) f32 array.
 
     ``keys``/``vals`` must already be padded to a multiple of ``chunk`` with
     sentinel keys (>= m*n) and zero values. ``m`` must be a multiple of
     ``block_rows`` (pad rows upstream). ``fold`` selects the in-tile
-    accumulation strategy (see module docstring); the vectorized folds
-    require a power-of-two ``chunk`` and, for bit-identity with the
-    canonical contract, a stream pre-sorted by key (stable).
+    accumulation strategy (see module docstring); bit-identity with the
+    canonical contract needs a stream pre-sorted by key (stable).
+    Interpreter-only.
     """
+    require_interpreter("spa_accumulate_raw")
     if keys.shape != vals.shape or keys.ndim != 1:
         raise ValueError(f"keys/vals must be matching 1-D streams, got "
                          f"{keys.shape} vs {vals.shape}")
@@ -94,15 +91,12 @@ def spa_accumulate_raw(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
         raise ValueError("pad inputs to a chunk multiple")
     if fold not in _vec.FOLDS:
         raise ValueError(f"unknown fold {fold!r}; one of {_vec.FOLDS}")
-    if fold != "serial" and chunk & (chunk - 1) != 0:
-        raise ValueError(
-            "vectorized folds need a power-of-two chunk (bitonic network)")
     parts = (m + block_rows - 1) // block_rows
     m_pad = parts * block_rows
     num_chunks = keys.shape[0] // chunk
 
     kernel = functools.partial(_spa_kernel, m=m, n=n, block_rows=block_rows,
-                               chunk=chunk, fold=fold)
+                               fold=fold)
     out = pl.pallas_call(
         kernel,
         grid=(parts, num_chunks),
@@ -112,6 +106,6 @@ def spa_accumulate_raw(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
         ],
         out_specs=pl.BlockSpec((block_rows, n), lambda i, c: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), jnp.float32),
-        interpret=interpret,
+        interpret=True,
     )(keys, vals)
     return out[:m]

@@ -6,7 +6,8 @@ elements sorts far more than needed; production systems select top-(k/nb)
 within fixed blocks (SparCML-style). This kernel does one block per grid
 cell: the block lives in VMEM, selection runs as k rounds of
 max+mask (k ≪ block, so O(k·block) beats a full sort), and indices are
-emitted globally offset. ref.topk_block_ref is the oracle.
+emitted globally offset. ref.topk_block_ref is the oracle. Interpreter-only:
+a CPU reference that refuses to launch on a TPU.
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.compat import pallas as pl
+from repro.compat import require_interpreter
 
 
 def _topk_kernel(x_ref, idx_ref, val_ref, *, block: int, k: int):
@@ -35,10 +37,10 @@ def _topk_kernel(x_ref, idx_ref, val_ref, *, block: int, k: int):
     jax.lax.fori_loop(0, k, body, (mag, 0))
 
 
-def topk_block_raw(x: jax.Array, *, k: int, block: int,
-                   interpret: bool = True):
+def topk_block_raw(x: jax.Array, *, k: int, block: int):
     """x: (nb*block,) -> (idx (nb*k,), val (nb*k,)); top-k by |value| per
     block."""
+    require_interpreter("topk_block_raw")
     if x.shape[0] % block != 0:
         raise ValueError(f"input length {x.shape[0]} must be a multiple of "
                          f"block {block}")
@@ -52,6 +54,6 @@ def topk_block_raw(x: jax.Array, *, k: int, block: int,
                    pl.BlockSpec((k,), lambda b: (b,))],
         out_shape=[jax.ShapeDtypeStruct((nb * k,), jnp.int32),
                    jax.ShapeDtypeStruct((nb * k,), jnp.float32)],
-        interpret=interpret,
+        interpret=True,
     )(x)
     return idx, val

@@ -1,292 +1,127 @@
-"""Lane-parallel one-touch accumulation: vectorized in-tile folds.
+"""In-tile folds shared by the sliding accumulation grids.
 
-The sliding blocked-SPA kernel (``kernels/spa_accum.py``) streams chunks of
-(key, val) pairs through VMEM and folds them into a resident accumulator
-tile. Its original in-tile scatter was a serial ``fori_loop`` of one dynamic
-store *per input element* — O(chunk) dependent round-trips through the store
-unit, zero vector lanes (DESIGN.md §4). This module provides two
-lane-parallel replacements that plug into that same sliding grid:
+A sliding grid (``kernels/partition.py``, and the legacy row-tiled grid in
+``kernels/spa_accum.py``) keeps one accumulator tile of shape
+``(rows, width)`` resident in VMEM and streams chunks of (key, val) pairs
+past it. A fold adds one chunk into the tile. Every fold is called as
+``fold(keys_ref, vals_ref, out_ref, locate)``, where ``locate(keys)`` maps
+keys (a scalar or a vector) to ``(valid, slot)``: whether a key belongs to
+the resident tile, and its flat row-major offset in it. Slot ``s`` lives at
+row ``s // width``, lane ``s % width``.
 
-``sort_fold``
-    A **bitonic sort + run fold**: the chunk's (slot, val) pairs are sorted
-    in-register by an explicit jnp-lowered bitonic network (log²-depth of
-    fully vectorized compare-exchanges — VPU selects, no data-dependent
-    control flow), duplicate-key runs are located by log-depth integer scans
-    (head flags, run ids, run starts — all exact arithmetic), each run is
-    folded to a single total, and only the **run heads** are stored:
-    O(distinct-runs) serial stores per chunk instead of O(chunk).
+The two folds are written for Mosaic, the TPU kernel compiler:
 
-``onehot_fold``
-    A **one-hot MXU fold** for small accumulator tiles: after the same sort
-    + run fold, the per-run totals are scattered through a
-    ``(chunk × block_elems)`` one-hot matmul, so the MXU performs the entire
-    tile update and the chunk needs **zero** serial stores. Each one-hot
-    column holds at most one nonzero (runs are distinct keys), which keeps
-    the matmul bit-exact. Costs O(chunk·block_elems) FLOPs — worth it
-    exactly when the tile is small (see DESIGN.md §4 for the boundary).
+``serial``
+    One read-modify-write of the slot's tile row per input element. Keys and
+    values are read as scalars from SMEM; the row is updated under a lane
+    mask. O(chunk) row round-trips through VMEM.
 
-Bit-compatibility with the canonical ``compress_plan`` contract
----------------------------------------------------------------
-The engine promises every regime folds each key's contributions **in input
-stream order** (DESIGN.md §3.3) — float addition is not associative, so a
-log-depth *value* scan (tree-shaped sums) would break bit-identity. The
-log-depth machinery here therefore computes only the **integer run
-structure** (exact); the value fold itself is a *round-robin* loop over run
-offsets: step j adds element j of every run to that run's total
-simultaneously — fully vectorized across runs/lanes, serial depth equal to
-the **maximum duplicate multiplicity** in the chunk (not the chunk length),
-and each run's total is built strictly left-to-right.
+``onehot``
+    The fold for small tiles. Keys and values arrive as one ``(1, chunk)``
+    VMEM vector. A log-step scan (lane rolls) gives every element its rank
+    inside its run of equal slots; round ``j`` then scatters the ``j``-th
+    element of every run into the tile with one one-hot MXU matmul, so the
+    tile update needs no serial stores. Rounds = the longest run in the
+    chunk. Needs equal keys to be contiguous in the chunk, which a
+    key-sorted stream guarantees.
 
-Across chunks, every run total is **initialized from the accumulator's
-current value and stored back by overwrite**, so a key whose duplicates span
-chunk boundaries continues the same left-fold chain
-``((prefix + v_a) + v_b)`` instead of re-associating as
-``prefix + (v_a + v_b)``. Given an input stream pre-sorted by key (stable —
-``ops.vec_accumulate`` does this; the engine's canonical plan order is
-exactly that sort), the result is bit-identical to the serial scatter and to
-``jax.ops.segment_sum`` over the sorted stream — the canonical contract.
-
-The kernels validate in interpret mode (like every kernel in this package);
-the bitonic network and the one-hot matmul are the pieces that map onto VPU
-lanes / the MXU on real hardware, which is the point of this design.
+Bit-identity with the canonical ``compress_plan`` contract (DESIGN.md
+§3.3): every fold applies each contribution as ``acc[s] = acc[s] + v``, in
+stream order, starting from the tile's current value. That is the left fold
+``jax.ops.segment_sum`` performs over the stable-sorted stream, across chunk
+boundaries included. The one-hot matmul is exact because each output cell
+receives at most one nonzero product per round, at ``HIGHEST`` precision.
 """
 from __future__ import annotations
 
-from typing import Tuple
-
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from repro.compat import pallas as pl
+from repro.compat import pallas_tpu as pltpu
 
 
-def _iota(n: int) -> jax.Array:
-    """1-D iota via the TPU-safe 2-D form (1-D iota does not lower)."""
-    return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0).reshape(n)
+def _lane_ids(width: int) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
 
 
-# ---------------------------------------------------------------------------
-# bitonic sort network (stable by (key, input-position) composite compare)
-# ---------------------------------------------------------------------------
+def serial_fold(keys_ref, vals_ref, out_ref, locate) -> None:
+    """One masked tile-row read-modify-write per input element."""
+    width = out_ref.shape[-1]
+    lane = _lane_ids(width)
 
-def _compare_exchange(keys, idx, vals, stride: int, block: int):
-    """One vectorized bitonic compare-exchange layer at ``stride`` within
-    bitonic blocks of size ``block``. Pairs (i, i^stride) compare on the
-    composite (key, idx) — idx is the original position, so equal keys keep
-    a deterministic (stable) order without widening the key dtype."""
-    n = keys.shape[0]
-    g = n // (2 * stride)
-    k2 = keys.reshape(g, 2, stride)
-    i2 = idx.reshape(g, 2, stride)
-    v2 = vals.reshape(g, 2, stride)
-    klo, khi = k2[:, 0], k2[:, 1]
-    ilo, ihi = i2[:, 0], i2[:, 1]
-    vlo, vhi = v2[:, 0], v2[:, 1]
-    # direction bit: ascending iff bit log2(block) of the global index is 0;
-    # constant per 2*stride group because 2*stride <= block.
-    first = jax.lax.broadcasted_iota(jnp.int32, (g, 1), 0) * (2 * stride)
-    asc = (first & block) == 0
-    gt = (klo > khi) | ((klo == khi) & (ilo > ihi))
-    swap = jnp.where(asc, gt, jnp.logical_not(gt))
-    new_lo = (jnp.where(swap, khi, klo), jnp.where(swap, ihi, ilo),
-              jnp.where(swap, vhi, vlo))
-    new_hi = (jnp.where(swap, klo, khi), jnp.where(swap, ilo, ihi),
-              jnp.where(swap, vlo, vhi))
-    pack = lambda lo, hi: jnp.stack([lo, hi], axis=1).reshape(n)
-    return (pack(new_lo[0], new_hi[0]), pack(new_lo[1], new_hi[1]),
-            pack(new_lo[2], new_hi[2]))
+    def body(e, carry):
+        valid, slot = locate(keys_ref[e])
+
+        @pl.when(valid)
+        def _add():
+            r = slot // width
+            row = out_ref[pl.ds(r, 1), :]
+            out_ref[pl.ds(r, 1), :] = jnp.where(lane == slot % width,
+                                                row + vals_ref[e], row)
+
+        return carry
+
+    jax.lax.fori_loop(0, keys_ref.shape[0], body, 0)
 
 
-def bitonic_sort_chunk(keys: jax.Array, vals: jax.Array
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """Sort (keys, vals) ascending by key, **stable**, via an explicit
-    bitonic network. ``len(keys)`` must be a power of two (static). The
-    network is log²-depth; every layer is a reshaped vectorized select —
-    no gathers, no data-dependent control flow."""
-    n = keys.shape[0]
-    if n & (n - 1) != 0:
-        raise ValueError("bitonic sort needs a power-of-two chunk")
-    idx = _iota(n)
-    stages = n.bit_length() - 1
-    for stage in range(1, stages + 1):
-        block = 1 << stage
-        for sub in range(stage, 0, -1):
-            keys, idx, vals = _compare_exchange(keys, idx, vals,
-                                                1 << (sub - 1), block)
-    return keys, vals
-
-
-# ---------------------------------------------------------------------------
-# run structure (log-depth integer scans — exact, so tree order is safe)
-# ---------------------------------------------------------------------------
-
-def run_structure(slot_s: jax.Array, valid_s: jax.Array):
-    """Locate duplicate runs in a *sorted* slot array.
-
-    Returns ``(head, gid, maxlen)``: first-occurrence flags, run ids
-    (invalid slots inherit the last run's id — harmless, their values are
-    masked to 0), and the maximum run length (serial depth of the value
-    fold). All integer/boolean log-depth scans — exact arithmetic, so the
-    tree-shaped scan order cannot perturb float results.
-    """
-    n = slot_s.shape[0]
-    pos = _iota(n)
-    prev = jnp.concatenate([jnp.full((1,), -1, slot_s.dtype), slot_s[:-1]])
-    head = valid_s & (slot_s != prev)
-    gid = jnp.clip(jnp.cumsum(head.astype(jnp.int32)) - 1, 0, n - 1)
-    # inclusive max-scan: position of the most recent head at-or-before i
+def run_offsets(slot: jax.Array, valid: jax.Array) -> jax.Array:
+    """Rank of each element inside its run of equal slots, for ``(1, n)``
+    vectors whose equal slots are contiguous. A log-step max-scan (lane
+    rolls) carries the position of the latest run head; integer-only, so
+    the scan order cannot perturb any float result."""
+    n = slot.shape[-1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, slot.shape, 1)
+    head = valid & ((slot != pltpu.roll(slot, 1, 1)) | (pos == 0))
     start = jnp.where(head, pos, -1)
     d = 1
     while d < n:
-        shifted = jnp.concatenate([jnp.full((d,), -1, start.dtype),
-                                   start[:-d]])
-        start = jnp.maximum(start, shifted)
+        start = jnp.maximum(start,
+                            jnp.where(pos >= d, pltpu.roll(start, d, 1), -1))
         d *= 2
-    offset = pos - start
-    maxlen = jnp.max(jnp.where(valid_s, offset, -1)) + 1
-    return head, gid, maxlen
+    return pos - start
 
 
-def fold_runs(vals_s: jax.Array, head: jax.Array, gid: jax.Array,
-              maxlen: jax.Array, init: jax.Array) -> jax.Array:
-    """Fold each run's values **in stream order** (left-associated), starting
-    from ``init`` (the accumulator's current value at the run's slot).
+def onehot_fold(keys_ref, vals_ref, out_ref, locate) -> None:
+    """Scatter the chunk into the tile with one one-hot matmul per rank."""
+    rows, width = out_ref.shape
+    keys = keys_ref[...]
+    keys = keys.reshape(1, keys.size)
+    valid, slot = locate(keys)
+    vals = jnp.where(valid, vals_ref[...].reshape(keys.shape), 0.0)
+    slot = jnp.where(valid, slot, 0)
+    rank = run_offsets(slot, valid)
+    rounds = jnp.max(jnp.where(valid, rank + 1, 0))
+    row_of, lane_of = slot // width, slot % width
+    row_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    lane_ids = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
 
-    Round-robin over run offsets: iteration j adds element j of *every* run
-    to its total simultaneously — one vectorized shift + masked add per
-    step, serial depth = max run length. Runs already exhausted receive an
-    exact ``+ 0.0`` (never ``-0.0``: contributions are masked to ``+0.0``),
-    so their totals are bitwise untouched.
-    """
-    n = vals_s.shape[0]
-    totals0 = jnp.where(head, init, 0.0)
-    pad_v = jnp.concatenate([vals_s, jnp.zeros_like(vals_s)])
-    pad_g = jnp.concatenate([gid, jnp.full_like(gid, -1)])
+    def add_rank(j, tile):
+        sel = valid & (rank == j)
+        a = jnp.where(sel & (row_ids == row_of), vals, 0.0)     # (rows, n)
+        b = jnp.where(sel & (lane_ids == lane_of), 1.0, 0.0)    # (width, n)
+        return tile + jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
 
-    def cond(state):
-        j, _ = state
-        return j < maxlen
-
-    def body(state):
-        j, totals = state
-        sv = jax.lax.dynamic_slice(pad_v, (j,), (n,))
-        sg = jax.lax.dynamic_slice(pad_g, (j,), (n,))
-        contrib = jnp.where(head & (sg == gid), sv, 0.0)
-        return j + 1, totals + contrib
-
-    _, totals = jax.lax.while_loop(cond, body,
-                                   (jnp.int32(0), totals0))
-    return totals
-
-
-def _sorted_run_totals(slot: jax.Array, vals: jax.Array, valid: jax.Array,
-                       out_flat: jax.Array, block_elems: int):
-    """Shared front half of both folds: stable-sort the masked chunk, find
-    runs, and fold each run left-to-right starting from the accumulator's
-    current value at its slot. Returns (slot_s, head, totals, nruns)."""
-    invalid_slot = jnp.int32(block_elems)
-    slot_m = jnp.where(valid, slot, invalid_slot)
-    vals_m = jnp.where(valid, vals, 0.0).astype(jnp.float32)
-    slot_s, vals_s = bitonic_sort_chunk(slot_m, vals_m)
-    valid_s = slot_s < block_elems
-    head, gid, maxlen = run_structure(slot_s, valid_s)
-    init = out_flat[jnp.clip(slot_s, 0, block_elems - 1)]
-    totals = fold_runs(vals_s, head, gid, maxlen, init)
-    nruns = head.sum().astype(jnp.int32)
-    return slot_s, head, totals, nruns
-
-
-# ---------------------------------------------------------------------------
-# the in-tile folds (called from the sliding grids in spa_accum.py and
-# partition.py; `slot` is the tile-local flat offset, `block_elems` marks
-# masked elements)
-# ---------------------------------------------------------------------------
-
-def serial_fold(slot: jax.Array, vals: jax.Array, valid: jax.Array,
-                out_ref, *, n_cols: int) -> None:
-    """The original fidelity baseline: one dynamic store per input element
-    (O(chunk) dependent round-trips through the store unit). Masked
-    elements add an exact ``+0.0`` at tile slot 0, matching the reference
-    oracle's discard convention."""
-    from repro.compat import pallas as pl
-
-    slot_safe = jnp.where(valid, slot, 0)
-    vals_m = jnp.where(valid, vals, 0.0).astype(jnp.float32)
-    chunk = slot.shape[0]
-
-    def body(e, _):
-        s = slot_safe[e]
-        r, c = s // n_cols, s % n_cols
-        cur = pl.load(out_ref, (r, c))
-        pl.store(out_ref, (r, c), cur + vals_m[e])
-        return 0
-
-    jax.lax.fori_loop(0, chunk, body, 0)
-
-
-def sort_fold(slot: jax.Array, vals: jax.Array, valid: jax.Array,
-              out_ref, *, n_cols: int) -> None:
-    """Bitonic sort-fold: sort, fold runs, store **one total per distinct
-    run** (compacted, O(distinct) serial stores) by overwrite — each total
-    already continues the accumulator's prefix, which is what keeps the
-    cross-chunk fold left-associated."""
-    from repro.compat import pallas as pl
-
-    block_elems = out_ref.shape[0] * out_ref.shape[1]
-    out_flat = out_ref[...].reshape(block_elems)
-    slot_s, head, totals, nruns = _sorted_run_totals(slot, vals, valid,
-                                                     out_flat, block_elems)
-    n = slot_s.shape[0]
-    # compact (slot, total) of each run head to the front: run g at index g
-    scatter_idx = jnp.where(head, jnp.clip(jnp.cumsum(
-        head.astype(jnp.int32)) - 1, 0, n - 1), n)
-    run_slot = jnp.zeros((n,), jnp.int32).at[scatter_idx].set(
-        slot_s, mode="drop")
-    run_total = jnp.zeros((n,), jnp.float32).at[scatter_idx].set(
-        totals, mode="drop")
-
-    def store(g, _):
-        s = run_slot[g]
-        pl.store(out_ref, (s // n_cols, s % n_cols), run_total[g])
-        return 0
-
-    jax.lax.fori_loop(0, nruns, store, 0)
-
-
-def onehot_fold(slot: jax.Array, vals: jax.Array, valid: jax.Array,
-                out_ref, *, n_cols: int) -> None:
-    """One-hot MXU fold: sort, fold runs, then scatter every run total in a
-    single ``(chunk × block_elems)`` one-hot matmul — the MXU performs the
-    tile update, zero serial stores. Exact because each one-hot column
-    carries at most one nonzero (runs are distinct slots); untouched slots
-    keep their previous bits through the select."""
-    block_rows = out_ref.shape[0]
-    block_elems = block_rows * out_ref.shape[1]
-    out_tile = out_ref[...]
-    out_flat = out_tile.reshape(block_elems)
-    slot_s, head, totals, _ = _sorted_run_totals(slot, vals, valid,
-                                                 out_flat, block_elems)
-    n = slot_s.shape[0]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (n, block_elems), 1)
-    onehot = (head[:, None] & (slot_s[:, None] == cols)).astype(jnp.float32)
-    contrib = jnp.dot(totals[None, :], onehot,
-                      preferred_element_type=jnp.float32).reshape(block_elems)
-    touched = jnp.max(onehot, axis=0) > 0.0
-    new_flat = jnp.where(touched, contrib, out_flat)
-    out_ref[...] = new_flat.reshape(block_rows, out_ref.shape[1])
+    out_ref[...] = jax.lax.fori_loop(0, rounds, add_rank, out_ref[...])
 
 
 #: fold-mode registry the sliding grids dispatch on (static, per launch).
-FOLDS = ("serial", "sort", "onehot")
+FOLDS = ("serial", "onehot")
+
+#: folds that read keys/values as scalars, so their input blocks live in SMEM
+SCALAR_FOLDS = ("serial",)
 
 #: fold name -> in-tile fold fn, shared by the legacy row-tiled grid
 #: (spa_accum.py) and the one-pass partitioned grid (partition.py).
-FOLD_FNS = {"serial": serial_fold, "sort": sort_fold, "onehot": onehot_fold}
+FOLD_FNS = {"serial": serial_fold, "onehot": onehot_fold}
 
 
-def apply_fold(fold: str, slot: jax.Array, vals: jax.Array,
-               valid: jax.Array, out_ref, *, n_cols: int) -> None:
+def apply_fold(fold: str, keys_ref, vals_ref, out_ref, locate) -> None:
     """Dispatch the in-tile fold by (static) name."""
-    FOLD_FNS[fold](slot, vals, valid, out_ref, n_cols=n_cols)
+    FOLD_FNS[fold](keys_ref, vals_ref, out_ref, locate)
 
 
 # ---------------------------------------------------------------------------
@@ -295,30 +130,13 @@ def apply_fold(fold: str, slot: jax.Array, vals: jax.Array,
 
 def chunk_store_counts(keys, *, m: int, n: int, block_rows: int,
                        chunk: int) -> dict:
-    """Serial-store counts per kernel variant for a given input stream, as
-    the sliding grid would see it: the serial scatter issues ``chunk`` stores
-    per (part, chunk) cell; the sort-fold issues one store per distinct
-    in-band slot per cell; the one-hot fold issues none (MXU matmul).
+    """Tile-row stores per fold for a stream on the legacy row-tiled grid
+    (``(block_rows, n)`` tiles): ``serial`` stores once per element of every
+    (part, chunk) cell, ``onehot`` never (MXU matmul).
 
-    Host-side numpy — benchmark/observability only, not a traced path.
+    Host-side — benchmark/observability only, not a traced path.
     """
-    keys = np.asarray(keys)
     parts = (m + block_rows - 1) // block_rows
-    cap = len(keys)
-    cap_pad = ((max(cap, 1) + chunk - 1) // chunk) * chunk
-    num_chunks = cap_pad // chunk
-    keys_p = np.full(cap_pad, m * n, dtype=np.int64)
-    keys_p[:cap] = keys
-    # the vec wrappers pre-sort the stream by key (canonical plan order)
-    keys_sorted = np.sort(keys_p, kind="stable")
-    serial = parts * num_chunks * chunk
-    vec = 0
-    for p in range(parts):
-        row_lo, row_hi = p * block_rows, (p + 1) * block_rows
-        for c in range(num_chunks):
-            ck = keys_sorted[c * chunk:(c + 1) * chunk]
-            rows = ck % m
-            in_band = (ck < m * n) & (rows >= row_lo) & (rows < row_hi)
-            vec += len(np.unique(ck[in_band]))
-    return {"serial": serial, "sort_fold": vec, "onehot_fold": 0,
+    num_chunks = (max(len(keys), 1) + chunk - 1) // chunk
+    return {"serial": parts * num_chunks * chunk, "onehot_fold": 0,
             "parts": parts, "num_chunks": num_chunks}

@@ -16,7 +16,8 @@ module and do the accounting ourselves, recursively multiplying loop bodies:
   reduce-scatter / all-to-all / collective-permute.
 
 All quantities are PER DEVICE (the module is the per-device SPMD program).
-Hardware constants are the assignment's v5e-class numbers.
+Hardware peaks are v5e's; a program analysed on a TPU of another kind is
+refused (:func:`check_device`), never measured against them.
 """
 from __future__ import annotations
 
@@ -25,11 +26,27 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import jax
+
 from repro.compat import cost_analysis_dict
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+#: ``jax.Device.device_kind`` of the chip whose peaks these are
+DEVICE_KIND = "TPU v5 lite"
+#: Published v5e per-chip peaks (Google Cloud documentation, "TPU v5e"):
+#: bf16 FLOP/s, HBM bytes/s, and ICI bytes/s per link (1,600 Gbit/s of
+#: chip-to-chip interconnect over 4 links).
+PEAK_FLOPS = 197e12
+HBM_BW = 819e9
+ICI_BW = 50e9
+
+
+def check_device(device) -> None:
+    """Refuse a TPU whose peaks are not v5e's. CPU devices pass: the dry
+    run compiles on CPU placeholders that stand in for a v5e mesh."""
+    if device.platform == "tpu" and device.device_kind != DEVICE_KIND:
+        raise ValueError(f"roofline peaks are {DEVICE_KIND!r}'s, not "
+                         f"{device.device_kind!r}'s")
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -346,6 +363,9 @@ class Roofline:
 
 
 def analyze_compiled(compiled) -> Roofline:
+    """Roofline of a compiled program against v5e peaks, on the devices of
+    this process (:func:`check_device`)."""
+    check_device(jax.devices()[0])
     cost_xla = cost_analysis_dict(compiled)
     mem = compiled.memory_analysis()
     analyzer = ModuleAnalyzer(compiled.as_text())

@@ -8,18 +8,20 @@ from __future__ import annotations
 
 import jax
 
+from repro.compat import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips/pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_dp_mesh(n: int | None = None):
     """Pure data-parallel mesh (the sparse-allreduce setting)."""
     n = n or len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 def make_dp_tp_mesh(data: int | None = None, model: int = 1):
@@ -32,7 +34,7 @@ def make_dp_tp_mesh(data: int | None = None, model: int = 1):
         if n % model:
             raise ValueError(f"{n} devices do not split into model={model}")
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def chips(mesh) -> int:

@@ -42,7 +42,8 @@ def main():
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, len(jax.devices())), ("data", "model"))
+    from repro.compat import make_mesh
+    mesh = make_mesh((1, len(jax.devices())), ("data", "model"))
 
     with mesh_context(mesh):
         params = model.init(jax.random.PRNGKey(0))

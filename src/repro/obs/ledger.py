@@ -55,7 +55,6 @@ LEDGER_NAME = "ledger.jsonl"
 TRACKED_ORACLES: Tuple[str, ...] = (
     "io/*/onepass_loads",       # spkadd_io: modeled one-pass chunk loads
     "smoke/serial_stores",      # table34: serial-fold store count
-    "smoke/sort_fold_stores",   # table34: vec sort-fold store count
     "allreduce*coll_bytes",     # sparse_allreduce: per-step collective bytes
     "chaos/*/bytes_per_sync",       # delta_sync: wire bytes per sync epoch
     "chaos/*/catchup_window_max",   # delta_sync: worst catch-up SpKAdd k

@@ -278,10 +278,9 @@ def test_expected_sorts_table():
 def test_spkj202_catches_i64_reaching_pallas_call():
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     from repro import compat
 
-    pl = compat.require_pallas()
+    pl = compat.pallas
 
     def copy_kernel(x_ref, o_ref):
         o_ref[...] = x_ref[...].astype(jnp.float32)
@@ -292,7 +291,7 @@ def test_spkj202_catches_i64_reaching_pallas_call():
             out_shape=jax.ShapeDtypeStruct((8,), jnp.float32),
             interpret=True)(idx)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(launch)(np.arange(8, dtype=np.int64))
     fs = JR.index_dtype_findings(closed, "fixture")
     assert rules_of(fs) == ["SPKJ202"]
@@ -344,7 +343,7 @@ def test_spkj203_real_partition_steps_are_legal():
 
 def test_spkj204_overspilled_geometry_is_flagged():
     fs = vmem.check_launch(
-        cap=1 << 16, m=4096, n=4096, part_elems=1 << 22, chunk=1024,
+        cap=1 << 16, m=4096, n=4096, part_elems=1 << 23, chunk=1024,
         regime="vec",
         cost_model={"vec_onehot_max_block_elems": float(1 << 40)},
         label="forced-overspill")
@@ -358,12 +357,14 @@ def test_spkj204_default_matrix_is_clean():
 
 def test_working_set_formula_matches_runtime():
     from repro.kernels.ops import fold_working_set_bytes
-    assert fold_working_set_bytes("sort", tile_elems=1024, chunk=256) \
-        == 1024 * 4 + 2 * 256 * 8
+    # both pipeline buffers of the tile + double-buffered input chunks (+
+    # the one-hot fold's (rows, chunk) and (128, chunk) round matrices)
+    assert fold_working_set_bytes("serial", tile_elems=1024, chunk=256) \
+        == 2 * 1024 * 4 + 2 * 256 * 8
     assert fold_working_set_bytes("onehot", tile_elems=1024, chunk=256) \
-        == 1024 * 4 + 2 * 256 * 8 + 256 * 1024 * 8
-    assert vmem.working_set_bytes("sort", part_elems=1024, chunk=256) \
-        == fold_working_set_bytes("sort", tile_elems=1024, chunk=256)
+        == 2 * 1024 * 4 + 2 * 256 * 8 + 256 * (1024 // 128 + 128) * 8
+    assert vmem.working_set_bytes("serial", part_elems=1024, chunk=256) \
+        == fold_working_set_bytes("serial", tile_elems=1024, chunk=256)
 
 
 # ---------------------------------------------------------------------------
@@ -440,7 +441,6 @@ def test_missing_baselines_empty_once_families_observed():
                 "geometry": ""},
         "records": [{"name": "io/64x8/onepass_loads", "value": 3.0},
                     {"name": "smoke/serial_stores", "value": 10.0},
-                    {"name": "smoke/sort_fold_stores", "value": 4.0},
                     {"name": "allreduce/p4/coll_bytes", "value": 128.0},
                     {"name": "chaos/ef/bytes_per_sync", "value": 700.0},
                     {"name": "chaos/ef/catchup_window_max", "value": 4.0},

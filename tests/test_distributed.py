@@ -1,5 +1,12 @@
 """Distributed behaviour (subprocess with fake CPU devices): sparse allreduce
 schedules, compressed training equivalence, distributed SpGEMM."""
+import json
+import os
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_sparse_allreduce_schedules_agree(multidevice):
@@ -10,7 +17,8 @@ from repro.compat import shard_map
 from repro.core.topk import topk_global
 from repro.core import allreduce as AR
 
-mesh = jax.make_mesh((8,), ('data',))
+from repro.compat import make_mesh
+mesh = make_mesh((8,), ('data',))
 rng = np.random.default_rng(2)
 size, kk = 1000, 50
 G = rng.standard_normal((8, size)).astype(np.float32)
@@ -47,7 +55,8 @@ from repro.compat import shard_map
 from repro.core.topk import topk_global
 from repro.core import allreduce as AR
 
-mesh = jax.make_mesh((8,), ('data',))
+from repro.compat import make_mesh
+mesh = make_mesh((8,), ('data',))
 rng = np.random.default_rng(5)
 size, kk = 400, 40
 G = rng.standard_normal((8, size)).astype(np.float32)
@@ -88,7 +97,8 @@ opt = adamw_init(params)
 hp = TrainHParams(ce_chunk=16, attn_chunk=16, remat=False, total_steps=100,
                   warmup=0)
 shape = ShapeConfig('t', 'train', 32, 8)
-mesh = jax.make_mesh((8,), ('data',))
+from repro.compat import make_mesh
+mesh = make_mesh((8,), ('data',))
 
 dense = jax.jit(make_train_step(m, hp))
 comp = jax.jit(make_compressed_train_step(m, mesh, hp, k_fraction=1.0,
@@ -135,7 +145,8 @@ opt = adamw_init(params)
 hp = TrainHParams(ce_chunk=16, attn_chunk=16, remat=False, total_steps=100,
                   warmup=0)
 shape = ShapeConfig('t', 'train', 32, 8)
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+from repro.compat import make_mesh
+mesh = make_mesh((4, 2), ('data', 'model'))
 
 dense = jax.jit(make_train_step(m, hp))
 # min_compress_elems lowered so the tiny model's matrices take the sparse
@@ -188,7 +199,8 @@ params = m.init(jax.random.PRNGKey(0))
 opt = adamw_init(params)
 hp = TrainHParams(ce_chunk=16, attn_chunk=16, remat=False, peak_lr=3e-3,
                   total_steps=1000, warmup=0, weight_decay=0.0)
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+from repro.compat import make_mesh
+mesh = make_mesh((4, 2), ('data', 'model'))
 shape = ShapeConfig('t', 'train', 32, 8)
 batch = make_batch(cfg, shape, 0)
 bsh = jax.tree.map(lambda x: jax.device_put(
@@ -236,7 +248,8 @@ def test_spgemm_summa_all_algorithms(multidevice):
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.spgemm import spgemm_summa
 rng = np.random.default_rng(3)
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+from repro.compat import make_mesh
+mesh = make_mesh((2, 2), ('data', 'model'))
 M, K, N = 32, 24, 16
 def sprand(m, n, frac=0.2):
     d = np.zeros((m, n), np.float32)
@@ -272,7 +285,8 @@ params = m.init(jax.random.PRNGKey(0))
 opt = adamw_init(params)
 hp = TrainHParams(ce_chunk=16, attn_chunk=16, remat=False, peak_lr=3e-3,
                   total_steps=1000, warmup=0, weight_decay=0.0)
-mesh = jax.make_mesh((4,), ('data',))
+from repro.compat import make_mesh
+mesh = make_mesh((4,), ('data',))
 step = jax.jit(make_compressed_train_step(m, mesh, hp, k_fraction=0.01))
 ef = init_ef_state(params, 4)
 shape = ShapeConfig('t', 'train', 32, 4)
@@ -286,3 +300,21 @@ for s in range(8):
 assert losses[-1] < losses[0], losses
 print('EF converges:', losses[0], '->', losses[-1])
 """, n_devices=4)
+
+
+@pytest.mark.parametrize("mesh", [(2, 1), (2, 2)], ids=["dp", "dp_tp"])
+def test_sparse_allreduce_bench_snippet_runs(multidevice, mesh):
+    """The byte benchmark's child program, at toy size, on a data-parallel
+    mesh and on a data x model mesh: both branches build their mesh and
+    emit the dense and compressed records."""
+    sys.path.insert(0, REPO)  # for `benchmarks` (namespace package)
+    from benchmarks.sparse_allreduce_bytes import SNIPPET
+    knobs = dict(layers=1, d_model=64, d_ff=128, vocab=256, batch=8, seq=8,
+                 fracs=(0.5,), scheds=("gather_kway",),
+                 min_compress_elems=1024, mesh=list(mesh))
+    out = multidevice(f"import sys; sys.argv = ['snippet', "
+                      f"{json.dumps(knobs)!r}]\n" + SNIPPET,
+                      n_devices=mesh[0] * mesh[1])
+    tag = "allreduce" if mesh[1] == 1 else "allreduce_2x2"
+    assert f"{tag}/dense/coll_bytes," in out
+    assert f"{tag}/topk0.5/gather_kway/step," in out

@@ -75,7 +75,8 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_analysis import ModuleAnalyzer
 
-mesh = jax.make_mesh((8,), ('data',))
+from repro.compat import make_mesh
+mesh = make_mesh((8,), ('data',))
 x = jax.ShapeDtypeStruct((8, 1024), jnp.float32)
 
 def f(x):
@@ -89,3 +90,21 @@ print('AR_BYTES', int(sum(c.coll.values())))
 """)
     bytes_ = int(out.strip().split("AR_BYTES")[1])
     assert bytes_ >= 1024 * 4  # at least one 4KiB all-reduce operand
+
+
+def test_peaks_refuse_an_unknown_device_kind():
+    """Roofline terms use v5e peaks; a TPU of another kind is refused, while
+    CPU placeholders (the dry run's stand-ins for a v5e mesh) pass."""
+    from types import SimpleNamespace
+
+    import pytest
+
+    from repro.launch.hlo_analysis import Roofline, check_device
+    check_device(SimpleNamespace(platform="tpu", device_kind="TPU v5 lite"))
+    check_device(SimpleNamespace(platform="cpu", device_kind="cpu"))
+    with pytest.raises(ValueError, match="TPU v4"):
+        check_device(SimpleNamespace(platform="tpu", device_kind="TPU v4"))
+    roof = Roofline(flops=197e12, hbm_bytes=0.0, coll_bytes=0.0,
+                    coll_by_kind={}, coll_counts={}, xla_flops_once=0.0,
+                    arg_bytes=0, out_bytes=0, temp_bytes=0)
+    assert roof.t_compute == 1.0 and roof.bottleneck == "compute"

@@ -342,7 +342,7 @@ def test_regression_gate_pass_and_fail_on_synthetic_history(tmp_path):
 
 def test_tracked_oracle_patterns():
     names = ["io/two_parts/onepass_loads", "smoke/serial_stores",
-             "smoke/sort_fold_stores", "allreduce/dense/coll_bytes",
+             "allreduce/dense/coll_bytes",
              "allreduce_4x2/topk0.05/gather_kway/coll_bytes",
              "table_er/auto/k=4/d=4", "io/two_parts/read_amplification"]
     tracked = ledger.tracked_names(names)

@@ -26,7 +26,7 @@ from repro.kernels import ops as kops
 from repro.kernels import ref
 from repro.kernels.partition import modeled_chunk_loads
 
-FOLDS = ["serial", "sort", "onehot"]
+FOLDS = ["serial", "onehot"]
 
 #: cost-model override forcing the vec regime regardless of shape.
 FORCE_VEC = {"tree_max_k": 0, "spa_max_accum_elems": 1.0,
@@ -66,10 +66,11 @@ def run_partitioned(keys, vals, *, m, n, part_elems, chunk, fold):
         keys, (m, n), part_elems=geom.part_elems, chunk=geom.chunk)
     vals_p = jnp.zeros(keys_p.shape, jnp.float32).at[:len(keys)].set(
         vals[plan.order].astype(jnp.float32))
-    return kops.partitioned_accumulate_flat(
+    acc = kops.partitioned_accumulate(
         keys_p, vals_p, steps.chunk_id, steps.part_id, m=m, n=n,
         part_elems=geom.part_elems, parts=geom.parts, chunk=geom.chunk,
         fold=fold)
+    return np.asarray(acc).reshape(-1)[:m * n]
 
 
 def flat_ref(keys, vals, *, m, n):
@@ -87,6 +88,7 @@ def flat_ref(keys, vals, *, m, n):
     (32, 8, 100, 256, 16),  # single-part degenerate
     (16, 4, 50, 8, 8),      # tiny parts: many empty + multi-part chunks
     (24, 4, 30, 128, 32),   # chunk > nnz: sentinel-tail padding
+    (8, 16, 200, 16, 8),    # more inputs than slots: runs cross chunks
 ])
 def test_partitioned_bitwise_vs_oracle(fold, m, n, nnz, part_elems, chunk):
     rng = np.random.default_rng(hash((m, n, nnz)) % 2**31)
@@ -132,7 +134,7 @@ def test_partitioned_empty_parts_and_all_sentinel(fold):
     np.testing.assert_array_equal(np.asarray(got), np.zeros(m * n, np.float32))
 
 
-@pytest.mark.parametrize("fold", ["sort", "onehot"])
+@pytest.mark.parametrize("fold", FOLDS)
 def test_partitioned_duplicate_heavy(fold):
     """90% duplicates: long runs spanning many chunks of one part."""
     rng = np.random.default_rng(7)
@@ -171,12 +173,13 @@ def test_engine_partitioned_multi_part_geometry():
         cat.keys, cat.shape, part_elems=geom.part_elems, chunk=geom.chunk)
     vals_p = jnp.zeros(keys_p.shape, jnp.float32).at[:cat.cap].set(
         cat.vals[plan.order])
-    flat = kops.partitioned_accumulate_flat(
+    acc = kops.partitioned_accumulate(
         keys_p, vals_p, steps.chunk_id, steps.part_id, m=64, n=8,
         part_elems=geom.part_elems, parts=geom.parts, chunk=geom.chunk,
-        fold="sort")
+        fold="serial")
     np.testing.assert_array_equal(
-        np.asarray(flat), flat_ref(cat.keys, cat.vals, m=64, n=8))
+        np.asarray(acc).reshape(-1)[:64 * 8],
+        flat_ref(cat.keys, cat.vals, m=64, n=8))
 
 
 def test_engine_single_stable_sort_per_call():
@@ -329,13 +332,14 @@ def test_choose_block_rows_never_exceeds_budget():
 def test_partitioned_geometry_budget_discipline():
     """part_elems rounds DOWN to the lane multiple under the budget NET of
     the double-buffered input chunk blocks — the whole launch footprint
-    (tile + 2×(keys, vals) chunks) fits VMEM whenever the budget can hold
-    the floor tile at all (floor: one lane multiple)."""
+    (both pipeline buffers of the tile + 2×(keys, vals) chunks) fits VMEM
+    whenever the budget can hold the floor tile at all (floor: one lane
+    multiple)."""
     for budget in (512, 700, 4096, 1 << 20):
         geom = kops.partitioned_launch_geometry(1024, m=512, n=64,
                                                 vmem_budget_bytes=budget)
-        footprint = geom.part_elems * 4 + 2 * geom.chunk * 8
-        if budget >= 128 * 4 + 2 * geom.chunk * 8:
+        footprint = 2 * geom.part_elems * 4 + 2 * geom.chunk * 8
+        if budget >= 2 * 128 * 4 + 2 * geom.chunk * 8:
             assert footprint <= budget, (budget, footprint)
         else:
             assert geom.part_elems == 128  # documented floor

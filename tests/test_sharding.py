@@ -83,7 +83,8 @@ from jax.sharding import PartitionSpec as P
 from repro.sharding.params import ef_spec, ef_shardings
 from repro.train import init_ef_state
 
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+from repro.compat import make_mesh
+mesh = make_mesh((4, 2), ('data', 'model'))
 sd = jax.ShapeDtypeStruct
 # DP-only layout (P, size): worker dim over data
 assert ef_spec(sd((4, 1000), jnp.float32), mesh) == P('data', None)

@@ -259,12 +259,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import save_checkpoint, restore_checkpoint
 
 tmp = tempfile.mkdtemp()
-mesh_a = jax.make_mesh((8,), ('data',))
+from repro.compat import make_mesh
+mesh_a = make_mesh((8,), ('data',))
 x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
 xa = jax.device_put(x, NamedSharding(mesh_a, P('data')))
 save_checkpoint(tmp, 1, {'x': xa})
 
-mesh_b = jax.make_mesh((2, 4), ('data', 'model'))
+mesh_b = make_mesh((2, 4), ('data', 'model'))
 sh = {'x': NamedSharding(mesh_b, P('data', 'model'))}
 out = restore_checkpoint(tmp, 1, {'x': x}, sh)
 np.testing.assert_array_equal(np.asarray(out['x']), np.asarray(x))

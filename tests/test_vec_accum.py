@@ -1,9 +1,10 @@
 """Lane-parallel accumulation folds (kernels/vec_accum) vs the oracles.
 
-The contract under test is stronger than numerical agreement: both
-vectorized folds (bitonic sort-fold and one-hot MXU fold) must be
-**bit-identical** to the pure-jnp reference (``kernels/ref.py``) *and* to
-the original serial in-tile scatter, on every stream shape — including
+The contract under test is stronger than numerical agreement: both folds
+(the serial row update and the one-hot MXU fold), run through the vec
+wrapper, must be **bit-identical** to the pure-jnp reference
+(``kernels/ref.py``) *and* to the legacy serial in-tile scatter, on every
+stream shape — including
 duplicate-heavy, all-sentinel, cancellation, and single-key-repeated
 chunks. That is what lets the engine swap the serial scatter for the
 vectorized folds without perturbing the canonical ``compress_plan``
@@ -17,7 +18,7 @@ from _hyp import given, settings, st  # hypothesis, or fallback shim
 
 from repro.kernels import ops, ref, vec_accum
 
-FOLDS = ["sort", "onehot"]
+FOLDS = ["serial", "onehot"]
 
 
 def make_stream(rng, m, n, nnz, pad, dup_frac=0.5):
@@ -44,45 +45,47 @@ def assert_bitwise(got, want, msg=""):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("size", [8, 32, 128])
-def test_bitonic_sort_is_stable(size):
-    """The network sorts ascending and keeps equal keys in input order
-    (required: stable order == canonical stream-order value folds)."""
+def test_run_offsets_rank_within_runs(size):
+    """Each element's rank inside its run of equal slots (the one-hot
+    fold's round index) matches a host-side count, with invalid elements
+    breaking runs as sentinels do."""
     rng = np.random.default_rng(size)
-    keys = rng.integers(0, 7, size=size).astype(np.int32)  # heavy ties
-    vals = np.arange(size, dtype=np.float32)  # value == input position
-    k_s, v_s = jax.jit(vec_accum.bitonic_sort_chunk)(jnp.asarray(keys),
-                                                     jnp.asarray(vals))
-    k_s, v_s = np.asarray(k_s), np.asarray(v_s)
-    assert (np.diff(k_s) >= 0).all(), "not sorted"
-    order = np.argsort(keys, kind="stable")
-    np.testing.assert_array_equal(k_s, keys[order])
-    np.testing.assert_array_equal(v_s, vals[order])  # stable tie order
+    slot = np.sort(rng.integers(0, 7, size=size)).astype(np.int32)
+    valid = rng.random(size) > 0.2
+    slot = np.where(valid, slot, 99)
+    want = np.zeros(size, np.int32)
+    for i in range(1, size):
+        if valid[i] and valid[i - 1] and slot[i] == slot[i - 1]:
+            want[i] = want[i - 1] + 1
+    got = jax.jit(vec_accum.run_offsets)(jnp.asarray(slot[None]),
+                                         jnp.asarray(valid[None]))
+    got = np.asarray(got)[0]
+    np.testing.assert_array_equal(got[valid], want[valid])
 
 
-def test_run_structure_counts_runs():
-    slot = jnp.asarray(np.array([0, 0, 2, 2, 2, 5, 9, 9], np.int32))
-    valid = jnp.asarray(np.array([1, 1, 1, 1, 1, 1, 0, 0], bool))
-    head, gid, maxlen = vec_accum.run_structure(slot, valid)
-    np.testing.assert_array_equal(np.asarray(head),
-                                  [1, 0, 1, 0, 0, 1, 0, 0])
-    np.testing.assert_array_equal(np.asarray(gid)[:6], [0, 0, 1, 1, 1, 2])
-    assert int(maxlen) == 3
+def test_run_offsets_counts_runs():
+    slot = jnp.asarray(np.array([[0, 0, 2, 2, 2, 5, 9, 9]], np.int32))
+    valid = jnp.asarray(np.array([[1, 1, 1, 1, 1, 1, 0, 0]], bool))
+    rank = np.asarray(jax.jit(vec_accum.run_offsets)(slot, valid))[0]
+    np.testing.assert_array_equal(rank[:6], [0, 1, 0, 1, 2, 0])
+    assert int(rank[:6].max()) + 1 == 3  # the one-hot fold's round count
 
 
-def test_fold_runs_is_left_associated():
-    """The round-robin fold must reproduce the exact left-fold bits —
-    values chosen so a tree-shaped sum (a+b)+(c+d) differs in the last
-    ulp from the stream fold ((a+b)+c)+d."""
+@pytest.mark.parametrize("fold", ["spa", "serial", "onehot"])
+def test_fold_is_left_associated(fold):
+    """Every fold must reproduce the exact left-fold bits — values chosen
+    so a tree-shaped sum (a+b)+(c+d) differs in the last ulp from the
+    stream fold ((a+b)+c)+d. ``spa`` is the legacy blocked-SPA wrapper."""
     vals = np.array([1e8, 1.0, 1.0, 1.0], np.float32)
-    slot = jnp.asarray(np.zeros(4, np.int32))
-    valid = jnp.ones(4, bool)
-    head, gid, maxlen = vec_accum.run_structure(slot, valid)
-    totals = vec_accum.fold_runs(jnp.asarray(vals), head, gid, maxlen,
-                                 jnp.zeros(4))
+    keys = jnp.zeros(4, jnp.int32)
+    got = ops.vec_accumulate(keys, jnp.asarray(vals), m=8, n=2, fold=fold,
+                             block_rows=8, chunk=4) if fold != "spa" else \
+        ops.spa_accumulate(keys, jnp.asarray(vals), m=8, n=2, block_rows=8,
+                           chunk=4)
     want = np.float32(0.0)
     for v in vals:
         want = np.float32(want + v)
-    assert np.asarray(totals)[0] == want
+    assert np.asarray(got)[0, 0] == want
 
 
 # ---------------------------------------------------------------------------
@@ -96,6 +99,7 @@ def test_fold_runs_is_left_associated():
     (128, 4, 100, 32, 128),     # chunk > nnz: padding path
     (56, 12, 200, 8, 32),       # m not a block multiple
     (8, 8, 64, 64, 16),         # block > m
+    (16, 16, 400, 8, 32),       # more inputs than slots: long runs
 ])
 def test_vec_accumulate_sweep_bitwise(fold, m, n, nnz, block_rows, chunk):
     rng = np.random.default_rng(hash((m, n, nnz)) % 2**31)
@@ -111,7 +115,7 @@ def test_vec_accumulate_sweep_bitwise(fold, m, n, nnz, block_rows, chunk):
 
 @pytest.mark.parametrize("fold", FOLDS)
 def test_vec_duplicate_heavy(fold):
-    """90% duplicates: long runs, the case the sort-fold exists for."""
+    """90% duplicates: long runs of one slot inside a chunk."""
     rng = np.random.default_rng(3)
     keys, vals = make_stream(rng, 16, 8, 400, pad=16, dup_frac=0.9)
     got = ops.vec_accumulate(keys, vals, m=16, n=8, fold=fold,
@@ -178,7 +182,7 @@ def test_vec_unsorted_stream_allclose(fold):
 
 
 def test_vec_auto_fold_selects_by_tile_size():
-    """fold="auto": one-hot for small tiles, sort-fold past the boundary —
+    """fold="auto": one-hot for small tiles, serial past the boundary —
     both bit-exact, so this only checks the switch doesn't change bits."""
     rng = np.random.default_rng(13)
     keys, vals = make_stream(rng, 64, 8, 200, pad=8)
@@ -197,7 +201,7 @@ def test_vec_auto_fold_selects_by_tile_size():
 @given(m=st.integers(4, 48), n=st.integers(1, 10), nnz=st.integers(1, 120),
        dup=st.floats(0.0, 0.95), seed=st.integers(0, 2**16))
 def test_property_vec_folds_bitwise_equal_serial(m, n, nnz, dup, seed):
-    """Property: for random shapes/duplicate rates, both vectorized folds
+    """Property: for random shapes/duplicate rates, both vec-wrapper folds
     are bit-identical to the serial scatter and the jnp reference."""
     rng = np.random.default_rng(seed)
     nnz = min(nnz, m * n * 2)
@@ -217,15 +221,13 @@ def test_property_vec_folds_bitwise_equal_serial(m, n, nnz, dup, seed):
 # serial-store accounting (the perf claim, measurable without a TPU)
 # ---------------------------------------------------------------------------
 
-def test_store_counts_reduced_to_distinct_runs():
+def test_store_counts_serial_per_cell_onehot_none():
+    """The serial fold stores once per element of every (part, chunk) cell
+    of the row-tiled grid; the one-hot fold never stores serially."""
     rng = np.random.default_rng(2)
     keys, _ = make_stream(rng, 32, 8, 300, pad=20, dup_frac=0.8)
     sc = ops.vec_store_counts(np.asarray(keys), m=32, n=8, block_rows=8,
                               chunk=32)
     assert sc["onehot_fold"] == 0
-    assert sc["sort_fold"] < sc["serial"]
-    # distinct keys bound the sort-fold stores from below; chunk boundaries
-    # can split a key's run across cells, never multiply it within one
-    distinct = len(np.unique(np.asarray(keys)[np.asarray(keys) < 32 * 8]))
-    assert sc["sort_fold"] >= distinct
+    assert (sc["parts"], sc["num_chunks"]) == (4, 10)
     assert sc["serial"] == sc["parts"] * sc["num_chunks"] * 32
