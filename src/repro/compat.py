@@ -4,7 +4,8 @@ The repo targets jax 0.9.0 only (``requirements.txt``). This module is the
 **one sanctioned home for ``jax.experimental`` imports** (spkaddlint rule
 SPK102): experimental APIs move between jax releases, so every consumer
 routes through the re-exports below (``pallas`` / ``pallas_tpu`` /
-``topologies``) and the next upgrade stays a one-file problem. It also
+``topologies`` / ``set_xla_metadata``) and the next upgrade stays a
+one-file problem. It also
 holds the two process-wide decisions that depend on the installed jax:
 how a mesh is built (:func:`make_mesh`) and whether Pallas kernels run
 through Mosaic or the interpreter (:func:`interpret_kernels`).
@@ -15,6 +16,7 @@ import jax
 from jax.experimental import pallas as pallas
 from jax.experimental import topologies as topologies
 from jax.experimental.pallas import tpu as pallas_tpu
+from jax.experimental.xla_metadata import set_xla_metadata as set_xla_metadata
 from jax.sharding import AxisType
 
 shard_map = jax.shard_map

@@ -335,12 +335,15 @@ def _run_spa(mats: Sequence[PaddedCOO],
     combines duplicate indices unspecified, and on a TPU an unsorted
     scatter does not add them in stream order, while a key-sorted one folds
     them as ``segment_sum`` does in the ``sorted`` regime."""
-    cat = concat(mats)
-    m, n = cat.shape
-    plan = compress_plan(cat.keys, cat.shape)
-    flat = scatter_accumulate(cat.keys[plan.order], cat.vals[plan.order],
-                              m * n)
-    return _canonical_from_plan(cat, plan, flat)
+    with obs.stage("spkadd.plan"):
+        cat = concat(mats)
+        m, n = cat.shape
+        plan = compress_plan(cat.keys, cat.shape)
+        keys_s, vals_s = cat.keys[plan.order], cat.vals[plan.order]
+    with obs.stage("spkadd.accumulate"):
+        flat = scatter_accumulate(keys_s, vals_s, m * n)
+    with obs.stage("spkadd.output"):
+        return _canonical_from_plan(cat, plan, flat)
 
 
 def _partition_fold(regime: str, geom, vmem_budget_bytes: int,
@@ -382,28 +385,38 @@ def _partitioned_core(keys: jax.Array, vals: jax.Array,
     fold = _partition_fold(regime, geom, vmem_budget_bytes, cost_model)
     obs.counter("engine.partitioned.launches").inc()
     obs.counter(f"engine.partitioned.fold.{fold}").inc()
-    with obs.span("engine.partitioned_launch", regime=regime, fold=fold,
-                  batch=keys.shape[0], cap=cap, parts=geom.parts,
-                  part_elems=geom.part_elems, chunk=geom.chunk,
-                  num_chunks=geom.num_chunks, max_steps=geom.max_steps):
+    with obs.stage("spkadd.plan"):
         plan, keys_p, steps = jax.vmap(functools.partial(
             plan_and_partition, shape=shape, part_elems=geom.part_elems,
             chunk=geom.chunk))(keys)
         vals_srt = jnp.take_along_axis(vals, plan.order, axis=-1)
         vals_p = jnp.zeros(keys_p.shape, jnp.float32).at[:, :cap].set(
             vals_srt.astype(jnp.float32))
+    with obs.stage("spkadd.accumulate"):
         acc = kops.partitioned_accumulate(
             keys_p, vals_p, steps.chunk_id, steps.part_id, m=m, n=n,
             part_elems=geom.part_elems, parts=geom.parts, chunk=geom.chunk,
             fold=fold)
 
     sent = sentinel_key(shape)
-    out_vals = jax.vmap(
-        lambda ok, p_nnz, b_acc: _canonical_gather(ok, p_nnz, b_acc, sent,
-                                                   vals.dtype)
-    )(plan.out_keys, plan.nnz, acc)
+    with obs.stage("spkadd.output"):
+        out_vals = jax.vmap(
+            lambda ok, p_nnz, b_acc: _canonical_gather(ok, p_nnz, b_acc,
+                                                       sent, vals.dtype)
+        )(plan.out_keys, plan.nnz, acc)
     return PaddedCOO(keys=plan.out_keys, vals=out_vals, nnz=plan.nnz,
                      shape=shape)
+
+
+def _unbatch(out: PaddedCOO, keys_stage: str) -> PaddedCOO:
+    """The single result of a B = 1 run of a shared core. The keys and
+    ``nnz`` are taken in ``keys_stage``, the stage that made them, so that
+    an op XLA fuses under their squeeze keeps its own stage."""
+    with obs.stage(keys_stage):
+        keys, nnz = out.keys[0], out.nnz[0]
+    with obs.stage("spkadd.output"):
+        vals = out.vals[0]
+    return PaddedCOO(keys=keys, vals=vals, nnz=nnz, shape=out.shape)
 
 
 def _run_partitioned(mats: Sequence[PaddedCOO], regime: str,
@@ -415,11 +428,12 @@ def _run_partitioned(mats: Sequence[PaddedCOO], regime: str,
     module docstring), then the I/O-optimal Pallas launch reads each input
     chunk exactly once and the canonical gather reuses the same plan.
     Runs the shared core as a B = 1 batch."""
-    cat = concat(mats)
-    out = _partitioned_core(cat.keys[None], cat.vals[None], cat.shape,
-                            regime, vmem_budget_bytes, cost_model)
-    return PaddedCOO(keys=out.keys[0], vals=out.vals[0], nnz=out.nnz[0],
-                     shape=cat.shape)
+    with obs.stage("spkadd.plan"):
+        cat = concat(mats)
+    # the keys and nnz of the partitioned core are the plan's
+    return _unbatch(_partitioned_core(cat.keys[None], cat.vals[None],
+                                      cat.shape, regime, vmem_budget_bytes,
+                                      cost_model), "spkadd.plan")
 
 
 def _run_blocked_spa(mats: Sequence[PaddedCOO],
@@ -467,10 +481,7 @@ def _hash_core(keys: jax.Array, vals: jax.Array, shape: Tuple[int, int],
         cap, m=m, n=n, vmem_budget_bytes=vmem_budget_bytes)
     obs.counter("engine.hash.launches").inc()
     sorts_before = sort_calls()
-    with obs.span("engine.hash_launch", batch=B, cap=cap,
-                  table_size=geom.table_size, parts=geom.parts,
-                  part_span=geom.part_span, chunk=geom.chunk,
-                  num_chunks=geom.num_chunks):
+    with obs.stage("spkadd.accumulate"):
         tkeys, tvals = kops.hash_slide_tables(
             keys, vals, m=m, n=n, table_size=geom.table_size,
             part_span=geom.part_span, parts=geom.parts, chunk=geom.chunk)
@@ -482,22 +493,24 @@ def _hash_core(keys: jax.Array, vals: jax.Array, shape: Tuple[int, int],
     # tables yields canonical order; the stable tie-break keeps sentinel
     # (empty) slots behind every real key.
     obs.counter("engine.hash.compaction_sorts").inc()
-    occupied = tkeys != -1
-    ck = jnp.where(occupied, tkeys, sent)
-    order = stable_argsort(ck)
-    ck_s = jnp.take_along_axis(ck, order, axis=-1)
-    cv_s = jnp.take_along_axis(tvals, order, axis=-1)
-    tab = ck.shape[-1]
-    if tab >= cap:
-        out_keys = ck_s[:, :cap]
-        out_f32 = cv_s[:, :cap]
-    else:
-        out_keys = jnp.concatenate(
-            [ck_s, jnp.full((B, cap - tab), sent, jnp.int32)], axis=-1)
-        out_f32 = jnp.concatenate(
-            [cv_s, jnp.zeros((B, cap - tab), jnp.float32)], axis=-1)
-    nnz = occupied.sum(axis=-1).astype(jnp.int32)
-    out_vals = jnp.where(out_keys != sent, out_f32, 0.0).astype(vals.dtype)
+    with obs.stage("spkadd.output"):
+        occupied = tkeys != -1
+        ck = jnp.where(occupied, tkeys, sent)
+        order = stable_argsort(ck)
+        ck_s = jnp.take_along_axis(ck, order, axis=-1)
+        cv_s = jnp.take_along_axis(tvals, order, axis=-1)
+        tab = ck.shape[-1]
+        if tab >= cap:
+            out_keys = ck_s[:, :cap]
+            out_f32 = cv_s[:, :cap]
+        else:
+            out_keys = jnp.concatenate(
+                [ck_s, jnp.full((B, cap - tab), sent, jnp.int32)], axis=-1)
+            out_f32 = jnp.concatenate(
+                [cv_s, jnp.zeros((B, cap - tab), jnp.float32)], axis=-1)
+        nnz = occupied.sum(axis=-1).astype(jnp.int32)
+        out_vals = jnp.where(out_keys != sent, out_f32,
+                             0.0).astype(vals.dtype)
     return PaddedCOO(keys=out_keys, vals=out_vals, nnz=nnz, shape=shape)
 
 
@@ -507,11 +520,10 @@ def _run_hash(mats: Sequence[PaddedCOO],
     """Sort-free sliding-hash regime: zero sorts before compaction, one
     stable sort total; output layout is canonical. Runs the shared core as
     a B = 1 batch."""
-    cat = concat(mats)
-    out = _hash_core(cat.keys[None], cat.vals[None], cat.shape,
-                     vmem_budget_bytes, cost_model)
-    return PaddedCOO(keys=out.keys[0], vals=out.vals[0], nnz=out.nnz[0],
-                     shape=cat.shape)
+    with obs.stage("spkadd.plan"):
+        cat = concat(mats)
+    return _unbatch(_hash_core(cat.keys[None], cat.vals[None], cat.shape,
+                               vmem_budget_bytes, cost_model), "spkadd.output")
 
 
 def _run_tree(mats: Sequence[PaddedCOO],
@@ -552,14 +564,24 @@ def _run_tree(mats: Sequence[PaddedCOO],
     return PaddedCOO(*out, shape)
 
 
+def _unphased(run):
+    """A regime with no separate plan and output phases (``tree``,
+    ``sorted``): its whole call is named ``spkadd.accumulate``."""
+    def staged(mats, cost_model=None):
+        with obs.stage("spkadd.accumulate"):
+            return run(mats, cost_model=cost_model)
+    return staged
+
+
 #: Engine-canonical paths: every entry returns the same PaddedCOO bitwise
 #: (the per-key value folds all happen in input-stream order). Entries share
 #: the signature ``(mats, cost_model=None)`` — the cost model carries
 #: regime-internal knobs (today: the vec one-hot boundary), so per-call
 #: overrides reach every regime uniformly.
 _CANONICAL = {
-    "tree": _run_tree,
-    "sorted": lambda mats, cost_model=None: _alg.spkadd_sorted(mats),
+    "tree": _unphased(_run_tree),
+    "sorted": _unphased(lambda mats, cost_model=None:
+                        _alg.spkadd_sorted(mats)),
     "spa": _run_spa,
     "vec": _run_vec,
     "blocked_spa": _run_blocked_spa,
@@ -694,10 +716,18 @@ def _run_partitioned_batched(stacked_mats: Sequence[PaddedCOO], regime: str,
     tables, ONE Pallas program with a leading batch grid dimension — the
     shared :func:`_partitioned_core` pipeline at B > 1. The single stable
     sort per call is preserved (one vmapped argsort)."""
-    keys = jnp.concatenate([a.keys for a in stacked_mats], axis=-1)  # (B, cap)
-    vals = jnp.concatenate([a.vals for a in stacked_mats], axis=-1)
+    keys, vals = _batched_streams(stacked_mats)
     return _partitioned_core(keys, vals, stacked_mats[0].shape, regime,
                              vmem_budget_bytes, cost_model)
+
+
+def _batched_streams(stacked_mats: Sequence[PaddedCOO]
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The ``(B, cap)`` concatenated key and value streams of a stacked
+    collection."""
+    with obs.stage("spkadd.plan"):
+        return (jnp.concatenate([a.keys for a in stacked_mats], axis=-1),
+                jnp.concatenate([a.vals for a in stacked_mats], axis=-1))
 
 
 def spkadd_batched(stacked_mats: Sequence[PaddedCOO], *,
@@ -723,8 +753,7 @@ def spkadd_batched(stacked_mats: Sequence[PaddedCOO], *,
     if effective == "hash":
         # native batched sliding-hash launch (leading batch grid dimension);
         # vmapping the B = 1 path would re-trace the Pallas call per batch
-        keys = jnp.concatenate([a.keys for a in stacked_mats], axis=-1)
-        vals = jnp.concatenate([a.vals for a in stacked_mats], axis=-1)
+        keys, vals = _batched_streams(stacked_mats)
         return _hash_core(keys, vals, stacked_mats[0].shape,
                           VMEM_BUDGET_BYTES, cost_model)
 
@@ -772,7 +801,8 @@ def spkadd_batched_ragged(collections: Sequence[Sequence[PaddedCOO]], *,
     distinct keys, extra sentinel slots).
     """
     results: List[Optional[PaddedCOO]] = [None] * len(collections)
-    buckets = bucket_collections(collections)
+    with obs.stage("spkadd.plan"):
+        buckets = bucket_collections(collections)
     obs.counter("engine.ragged.calls").inc()
     with obs.span("engine.spkadd_batched_ragged", algorithm=algorithm,
                   collections=len(collections), buckets=len(buckets)):
@@ -780,9 +810,11 @@ def spkadd_batched_ragged(collections: Sequence[Sequence[PaddedCOO]], *,
             obs.histogram("engine.ragged.bucket_occupancy").observe(
                 len(members))
             idxs = [i for i, _ in members]
-            stacked = stack_collections([padded for _, padded in members])
+            with obs.stage("spkadd.plan"):
+                stacked = stack_collections([padded for _, padded in members])
             out = spkadd_batched(stacked, algorithm=algorithm,
                                  cost_model=cost_model)
-            for b, i in enumerate(idxs):
-                results[i] = unstack_collection([out], b)[0]
+            with obs.stage("spkadd.output"):
+                for b, i in enumerate(idxs):
+                    results[i] = unstack_collection([out], b)[0]
     return results

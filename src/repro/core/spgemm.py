@@ -12,7 +12,9 @@ JAX mapping: the process grid is the (data=p_r, model=p_c) mesh; the
 broadcasts are ``all_gather`` along one mesh axis each (exactly SUMMA's
 communication pattern); blocks are dense tiles carrying sparse contents
 (static shapes), partials are sparsified to PaddedCOO and reduced with a
-selectable SpKAdd algorithm.
+selectable SpKAdd algorithm. Each piece of a stage's device work is named
+(``obs.stage``): ``summa.gather``, ``summa.multiply``, ``summa.from_dense``
+and ``summa.reduce``, inside which the engine names its own.
 """
 from __future__ import annotations
 
@@ -20,6 +22,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.compat import shard_map
 from repro.core.engine import spkadd_run as _spkadd_run
 from repro.core.sparse import from_dense as _from_dense
@@ -50,8 +53,9 @@ def spgemm_summa(a: jax.Array, b: jax.Array, mesh, *, algorithm: str = "auto",
     def worker(a_loc, b_loc):
         # SUMMA with stationary C: stages = p_c (A's block-cols = B's block-rows)
         # gather A's block-row stripe along 'model', B's block-col stripe along 'data'
-        a_stripe = jax.lax.all_gather(a_loc, "model", axis=1, tiled=True)
-        b_stripe = jax.lax.all_gather(b_loc, "data", axis=0, tiled=True)
+        with obs.stage("summa.gather"):
+            a_stripe = jax.lax.all_gather(a_loc, "model", axis=1, tiled=True)
+            b_stripe = jax.lax.all_gather(b_loc, "data", axis=0, tiled=True)
         m_loc = a_loc.shape[0]
         k_glob = a_stripe.shape[1]
         n_loc = b_loc.shape[1]
@@ -60,13 +64,18 @@ def spgemm_summa(a: jax.Array, b: jax.Array, mesh, *, algorithm: str = "auto",
         cap = partial_cap_per_stage or (m_loc * n_loc)
         partials = []
         for s in range(stages):
-            part = local_summa_stage(
-                jax.lax.dynamic_slice(a_stripe, (0, s * blk), (m_loc, blk)),
-                jax.lax.dynamic_slice(b_stripe, (s * blk, 0), (blk, n_loc)),
-            )
-            partials.append(_from_dense(part, cap=min(cap, m_loc * n_loc)))
-        c_sparse = _spkadd_run(partials, algorithm=algorithm)
-        return c_sparse.to_dense()
+            with obs.stage("summa.multiply"):
+                part = local_summa_stage(
+                    jax.lax.dynamic_slice(a_stripe, (0, s * blk),
+                                          (m_loc, blk)),
+                    jax.lax.dynamic_slice(b_stripe, (s * blk, 0),
+                                          (blk, n_loc)),
+                )
+            with obs.stage("summa.from_dense"):
+                partials.append(_from_dense(part,
+                                            cap=min(cap, m_loc * n_loc)))
+        with obs.stage("summa.reduce"):
+            return _spkadd_run(partials, algorithm=algorithm).to_dense()
 
     # check_vma=False: the vec/blocked_spa regimes run a pallas_call inside
     # the shard, and pallas_call has no replication rule

@@ -1,4 +1,4 @@
-"""Trace spans: zero-dependency, host-side, JSONL-exportable.
+"""Spans time host work; stages name device work.
 
 A span is a context manager that records a monotonic start time, a
 duration, a nesting depth/parent, and free-form key/value attributes:
@@ -11,12 +11,30 @@ Spans are recorded **only while observability is enabled** (the
 ``SPKADD_OBS`` env var, overridable per-process via :func:`set_enabled`).
 Disabled, :func:`span` returns a shared no-op context — no timestamp, no
 allocation of note, and (critically) no jit-traced ops ever: spans live
-entirely on the host, at trace/launch boundaries, so enabling or disabling
-them cannot perturb lowered HLO (``tests/test_obs.py`` pins this).
+entirely on the host, so enabling or disabling them cannot perturb lowered
+HLO (``tests/test_obs.py`` pins this).
 
-When a span opens while a jax profiler is importable, it also enters a
-``jax.profiler.TraceAnnotation`` of the same name, so engine/kernel spans
-show up on the host timeline of TPU traces.
+A span times the host work it wraps, and while a jax profiler is
+importable it also enters a ``jax.profiler.TraceAnnotation`` of the same
+name, so that work sits on the profiler's host timeline. Under
+``jax.jit`` the wrapped Python runs only while jax traces the function:
+such a span times that tracing, once per compile, and never fires in a
+steady run of the compiled program. An eager caller's spans (a dispatch
+decision, a stream flush) fire on every call.
+
+A **stage** names device work instead: ``with obs.stage("spkadd.plan"):``
+enters ``jax.named_scope`` and writes the XLA frontend attribute
+``obs_stage`` on every op traced inside it. The attribute survives
+compilation and appears in each op's HLO text in a device trace, so the
+benchmark reads each stage's device time from the trace. A stage adds no
+op — the compiled program differs only in attributes, metadata and
+instruction names — so stages are always on. Nested stages join their
+names with ``/`` (``summa.reduce/spkadd.plan``); names come from the
+closed set :data:`STAGES`. Where XLA fuses ops of two stages into one,
+the fused op carries the stage of its root; an op that a compiler pass
+makes anew without copying the attribute carries none (on a TPU: the
+cumsum's reduce-windows, and the sorts, gathers and dots that the
+partitioner re-creates inside ``shard_map``).
 
 Export: :func:`export_jsonl` writes one JSON object per finished span —
 ``{"name", "t_ns", "dur_ns", "depth", "parent", "attrs"}`` — the schema
@@ -26,17 +44,18 @@ CI captures a trace artifact from a benchmark subprocess without the
 benchmark knowing about tracing.
 
 Thread-safety note: the finished-span list is append-only under a lock;
-the *nesting stack* is thread-local, so spans opened on different threads
-get independent depth/parent chains.
+the span and stage *nesting stacks* are thread-local, so spans and stages
+opened on different threads get independent chains.
 """
 from __future__ import annotations
 
 import atexit
+import contextlib
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 #: Master switch: any value other than ""/"0"/"false"/"off" enables spans.
 OBS_ENV = "SPKADD_OBS"
@@ -44,6 +63,22 @@ OBS_ENV = "SPKADD_OBS"
 #: When set (and observability is enabled), finished spans are exported to
 #: this path at interpreter exit.
 OBS_JSONL_ENV = "SPKADD_OBS_JSONL"
+
+#: The closed set of stage names. A name must never hold a word that the
+#: benchmark's op classes match in HLO text (``all-gather``,
+#: ``tpu_custom_call``, ...).
+STAGES = (
+    "spkadd.plan",        # concatenation, plan sort, permutation, step tables
+    "spkadd.accumulate",  # the Pallas launch or scatter; sorted/tree whole
+    "spkadd.output",      # canonical gather, hash compaction
+    "summa.gather",       # the all-gathers of A's and B's stripes
+    "summa.multiply",     # the local multiply of each stage
+    "summa.from_dense",   # sparsifying each partial product
+    "summa.reduce",       # SpKAdd of the partials and to_dense
+)
+
+#: The XLA frontend attribute a stage writes on its ops.
+STAGE_ATTR = "obs_stage"
 
 _override: Optional[bool] = None
 _lock = threading.Lock()
@@ -64,10 +99,11 @@ def set_enabled(on: Optional[bool]) -> None:
     _override = on
 
 
-def _stack() -> list:
-    st = getattr(_tls, "stack", None)
+def _stack(attr: str = "stack") -> list:
+    st = getattr(_tls, attr, None)
     if st is None:
-        st = _tls.stack = []
+        st = []
+        setattr(_tls, attr, st)
     return st
 
 
@@ -157,6 +193,26 @@ def span(name: str, **attrs: Any):
     if not enabled():
         return _NULL
     return Span(name, dict(attrs))
+
+
+@contextlib.contextmanager
+def stage(name: str) -> Iterator[None]:
+    """Name the device work traced inside: ``jax.named_scope(name)`` and
+    the frontend attribute ``obs_stage`` set to the path of the enclosing
+    stages joined with ``/``. An unknown name raises ``ValueError``."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; known: {STAGES}")
+    import jax
+    from repro.compat import set_xla_metadata
+
+    st = _stack("stages")
+    st.append(name)
+    try:
+        with jax.named_scope(name), \
+                set_xla_metadata(**{STAGE_ATTR: "/".join(st)}):
+            yield
+    finally:
+        st.pop()
 
 
 def spans() -> List[Dict[str, Any]]:

@@ -1,7 +1,8 @@
-"""Observability layer (repro/obs): spans, metrics, ledger, regression gate,
-and the engine/streaming/allreduce instrumentation contracts.
+"""Observability layer (repro/obs): spans, stages, metrics, ledger,
+regression gate, and the engine/streaming/allreduce instrumentation
+contracts.
 
-The two hard promises under test:
+The three hard promises under test:
 
 1. **Disabled == invisible.** With ``SPKADD_OBS`` off, instrumented paths
    are bit-identical and lower to byte-identical HLO (no added jit-traced
@@ -9,6 +10,8 @@ The two hard promises under test:
 2. **The ledger has memory.** BENCH artifacts append into a keyed ledger
    (dedup by (commit, backend, suite, geometry)), and the regression gate
    trips on a synthetic regression but not on a flat trajectory.
+3. **Device work is named.** Every sort, gather, scatter, custom call and
+   call of a lowered engine or SUMMA program carries its stage.
 """
 import json
 import os
@@ -199,23 +202,187 @@ def test_obs_enabled_outputs_bit_identical():
 
 
 # ---------------------------------------------------------------------------
+# stages: device work named in the lowered program
+# ---------------------------------------------------------------------------
+
+#: cost models that force each canonical regime on ``random_collection``'s
+#: 6 x 24 inputs in a 32 x 8 key space
+FORCE = {
+    "tree": {"tree_max_k": 64},
+    "sorted": {"tree_max_k": 0, "spa_max_accum_elems": 0.0,
+               "hash_min_total_nnz": 1e18, "vec_max_accum_elems": 0.0,
+               "blocked_spa_max_accum_elems": 0.0},
+    "spa": {"tree_max_k": 0, "spa_max_accum_elems": 1e18,
+            "spa_min_density": 0.0},
+    "vec": FORCE_VEC,
+    "blocked_spa": {"tree_max_k": 0, "spa_max_accum_elems": 1.0,
+                    "hash_min_total_nnz": 1e18, "vec_max_accum_elems": 0.0,
+                    "blocked_spa_min_density": 0.0,
+                    "blocked_spa_max_accum_elems": 1e18},
+    "hash": {"tree_max_k": 0, "spa_max_accum_elems": 1.0,
+             "hash_min_total_nnz": 0.0, "hash_max_compression": 1e18},
+}
+
+#: the regimes with no separate phases run wholly as ``spkadd.accumulate``
+REGIME_STAGES = {
+    "tree": {"spkadd.accumulate"}, "sorted": {"spkadd.accumulate"},
+    **{r: {"spkadd.plan", "spkadd.accumulate", "spkadd.output"}
+       for r in ("spa", "vec", "blocked_spa", "hash")},
+}
+
+CHECKED_OPS = ("stablehlo.sort", "stablehlo.gather", "stablehlo.scatter",
+               "stablehlo.custom_call", "func.call")
+
+
+def _stage_attr(op):
+    attrs = op.attributes
+    if "mhlo.frontend_attributes" not in attrs:
+        return None
+    fa = attrs["mhlo.frontend_attributes"]
+    return str(fa[trace.STAGE_ATTR]).strip('"') \
+        if trace.STAGE_ATTR in fa else None
+
+
+def _nested_ops(op):
+    for region in op.regions:
+        for block in region.blocks:
+            for o in block.operations:
+                yield o.operation
+                yield from _nested_ops(o.operation)
+
+
+def module_stages(lowered):
+    """(every stage path in a lowered module, the checked ops that carry
+    none). A ``func.call`` that jax emits for a cached primitive lowering
+    (the cumsum's) has no attributes of its own; XLA inlines it, so it
+    takes the one stage its callee's ops carry."""
+    module = lowered.compiler_ir("stablehlo")
+    funcs = {str(f.attributes["sym_name"]).strip('"'): f
+             for f in module.body.operations}
+    seen, unstaged = set(), []
+    for f in funcs.values():
+        for op in _nested_ops(f):
+            path = _stage_attr(op)
+            if path is not None:
+                seen.add(path)
+            if op.name not in CHECKED_OPS:
+                continue
+            if path is None and op.name == "func.call":
+                callee = str(op.attributes["callee"]).lstrip("@")
+                inner = {p for o in _nested_ops(funcs[callee])
+                         if (p := _stage_attr(o)) is not None}
+                path = inner.pop() if len(inner) == 1 else None
+            if path is None:
+                unstaged.append(str(op)[:120])
+    return seen, unstaged
+
+
+@pytest.mark.parametrize("regime", sorted(FORCE))
+def test_every_regime_names_its_device_work(regime):
+    """Every sort, gather, scatter, custom call and call of the lowered
+    engine program carries a ``spkadd.`` stage, and each of the regime's
+    stages appears."""
+    mats = random_collection(21, 6, 32, 8, 24)
+    cm = FORCE[regime]
+    assert E.explain_dispatch(mats, cost_model=cm)[1] == regime
+    lowered = jax.jit(
+        lambda ms: E.spkadd_auto(ms, cost_model=cm)).lower(mats)
+    seen, unstaged = module_stages(lowered)
+    assert not unstaged
+    assert all(p.startswith("spkadd.") for p in seen)
+    assert REGIME_STAGES[regime] <= seen
+
+
+#: the stage that makes a result's keys and nnz: the plan's in the regimes
+#: that keep the plan's key layout, the compaction's in ``hash``
+KEYS_STAGE = {"spa": "spkadd.plan", "vec": "spkadd.plan",
+              "blocked_spa": "spkadd.plan", "hash": "spkadd.output"}
+
+
+@pytest.mark.parametrize("regime", sorted(KEYS_STAGE))
+def test_result_keys_keep_the_stage_that_made_them(regime):
+    """The last op of each result leaf is named by the stage that made the
+    leaf, so an op XLA fuses under it (its root) is not moved to another
+    stage: keys and nnz by ``KEYS_STAGE``, values by ``spkadd.output``."""
+    mats = random_collection(21, 6, 32, 8, 24)
+    cm = FORCE[regime]
+    module = jax.jit(lambda ms: E.spkadd_auto(ms, cost_model=cm)).lower(
+        mats).compiler_ir("stablehlo")
+    main = next(f for f in module.body.operations
+                if str(f.attributes["sym_name"]).strip('"') == "main")
+    ret = list(main.regions[0].blocks[0].operations)[-1]
+    keys, vals, nnz = (_stage_attr(v.owner) for v in ret.operands)
+    assert (keys, vals, nnz) == (KEYS_STAGE[regime], "spkadd.output",
+                                 KEYS_STAGE[regime])
+
+
+def test_summa_names_its_stages_and_nests_the_engine(multidevice):
+    code = """
+import json, re
+import jax, jax.numpy as jnp
+from repro.compat import make_mesh
+from repro.core import spgemm
+mesh = make_mesh((2, 2), ("data", "model"))
+a = jnp.zeros((16, 16), jnp.float32)
+text = jax.jit(lambda a, b: spgemm.spgemm_summa(a, b, mesh=mesh)).lower(
+    a, a).as_text()
+print(json.dumps(sorted(set(re.findall(r'obs_stage = "([^"]*)"', text)))))
+"""
+    seen = set(json.loads(multidevice(code, n_devices=4).splitlines()[-1]))
+    assert {"summa.gather", "summa.multiply", "summa.from_dense",
+            "summa.reduce"} <= seen
+    assert any(p.startswith("summa.reduce/spkadd.") for p in seen)
+
+
+def test_stage_rejects_unknown_names_and_nests_paths():
+    with pytest.raises(ValueError, match="unknown stage"):
+        with obs.stage("spkadd.sort"):
+            pass
+
+    def f(x):
+        with obs.stage("summa.reduce"):
+            y = x + 1
+            with obs.stage("spkadd.plan"):
+                y = y * 2
+        return y - 3
+
+    text = jax.jit(f).lower(jnp.ones(4)).as_text()
+    lines = {op: line for line in text.splitlines()
+             for op in ("add", "multiply", "subtract")
+             if f"stablehlo.{op} " in line}
+    assert 'obs_stage = "summa.reduce"}' in lines["add"]
+    assert 'obs_stage = "summa.reduce/spkadd.plan"' in lines["multiply"]
+    assert "obs_stage" not in lines["subtract"]
+
+
+# ---------------------------------------------------------------------------
 # instrumented paths emit the promised spans/counters
 # ---------------------------------------------------------------------------
 
 def test_engine_dispatch_span_and_counter():
+    """The dispatch decision is a span; the launch geometry is on the
+    partition gauges (device work is named by stages, not spans)."""
+    from repro.kernels import ops as kops
+
     trace.set_enabled(True)
     mats = random_collection(23, 6, 32, 8, 24)
+    geom = kops.partitioned_launch_geometry(6 * 24, m=32, n=8)
+    keys = ("parts", "part_elems", "chunk", "num_chunks")
+    for key in keys:
+        metrics.gauge(f"kernels.partition.{key}").set(-1)
     before = metrics.counter("engine.dispatch.vec").value
+    launches = metrics.counter("engine.partitioned.launches").value
     E.spkadd_auto(mats, cost_model=FORCE_VEC)
     assert metrics.counter("engine.dispatch.vec").value == before + 1
+    assert metrics.counter("engine.partitioned.launches").value \
+        == launches + 1
     autos = [r for r in trace.spans() if r["name"] == "engine.spkadd_auto"]
     assert autos and autos[-1]["attrs"]["selected"] == "vec"
     assert autos[-1]["attrs"]["k"] == 6
-    launches = [r for r in trace.spans()
-                if r["name"] == "engine.partitioned_launch"]
-    assert launches and launches[-1]["parent"] == "engine.spkadd_auto"
-    for key in ("parts", "part_elems", "chunk", "fold", "batch"):
-        assert key in launches[-1]["attrs"]
+    for key in keys:
+        assert metrics.gauge(f"kernels.partition.{key}").value \
+            == getattr(geom, key)
+    assert not [r for r in trace.spans() if r["parent"] == "engine.spkadd_auto"]
 
 
 def test_batched_dispatch_span_reports_requested_and_effective():
