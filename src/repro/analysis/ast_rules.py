@@ -239,8 +239,8 @@ def scan_source(source: str, rel: str) -> List[Finding]:
         if name in SORT_CALLS and rel != SORT_HOME:
             emit("SPK101", node,
                  f"direct {name}() outside {SORT_HOME}",
-                 "route through repro.core.sparse.stable_argsort / "
-                 "stable_sort (the counted canonical sort)")
+                 "route through repro.core.sparse.stable_sort_pairs / "
+                 "stable_argsort / stable_sort (the counted canonical sort)")
         # SPK104: spans must be `with` contexts at launch boundaries
         if name in SPAN_CALLS:
             allowed = rel in SPAN_ALLOWED_FILES \

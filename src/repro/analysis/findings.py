@@ -31,7 +31,8 @@ class Rule(NamedTuple):
 RULES: Dict[str, Rule] = {r.rule: r for r in [
     Rule("SPK101", "direct-sort",
          "jnp.sort/jnp.argsort/lax.sort only inside core/sparse.py — every "
-         "traced sort must pass through sparse.stable_argsort/stable_sort so "
+         "traced sort must pass through sparse.stable_argsort/stable_sort/"
+         "stable_sort_pairs so "
          "the one-sort invariant stays countable"),
     Rule("SPK102", "experimental-import",
          "jax.experimental imports only inside compat.py — version skew "

@@ -236,7 +236,7 @@ def check_entry_points() -> List[Finding]:
                 "SPKJ201", f"<jaxpr:{label}>", 0,
                 f"{n} sort primitive(s) in the closed jaxpr, expected "
                 f"{expected}",
-                "route every key sort through sparse.stable_argsort and "
+                "route every key sort through sparse.stable_sort_pairs and "
                 "share the canonical plan's sort (plan_and_partition) "
                 "instead of re-sorting"))
         findings.extend(index_dtype_findings(closed, label))

@@ -35,13 +35,15 @@ therefore swap regimes freely without perturbing checkpoints or tests.
 
 **Shared-sort contract (one-pass partitioned regimes).** The ``vec`` and
 ``blocked_spa`` regimes run the stream-partitioned sliding accumulator
-(:mod:`repro.kernels.partition`): the canonical plan's stable argsort is
-the *only* sort on the path — its order doubles as the partition sort
+(:mod:`repro.kernels.partition`): the canonical plan's stable sort is the
+*only* sort on the path — its order doubles as the partition order
 because parts are key-aligned ranges (``sparse.plan_and_partition``), the
 kernel wrappers take the pre-sorted stream and never re-sort, and each
 input chunk is read exactly once (the paper's I/O lower bound, vs the
 legacy grid's ``parts × N``). ``sparse.sort_calls()`` counts the stable
-sorts; tests pin the count at one per engine call.
+sorts; tests pin the count at one per engine call. Every regime's one
+sort carries the values as its payload (``sparse.stable_sort_pairs``), so
+no permutation is gathered after it.
 
 **Sort-free hash regime.** The ``hash`` regime (the paper's Tables 3/4
 winner) goes further: the *unsorted* stream is accumulated directly into
@@ -75,7 +77,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.sparse import (CompressPlan, PaddedCOO, compress_plan, concat,
                                next_pow2, plan_and_partition, sentinel_key,
-                               sort_calls, stable_argsort, with_capacity)
+                               sort_calls, stable_sort_pairs, with_capacity)
 from repro.core import spkadd as _alg
 from repro.kernels import VMEM_BUDGET_BYTES
 
@@ -338,10 +340,9 @@ def _run_spa(mats: Sequence[PaddedCOO],
     with obs.stage("spkadd.plan"):
         cat = concat(mats)
         m, n = cat.shape
-        plan = compress_plan(cat.keys, cat.shape)
-        keys_s, vals_s = cat.keys[plan.order], cat.vals[plan.order]
+        plan = compress_plan(cat.keys, cat.shape, cat.vals)
     with obs.stage("spkadd.accumulate"):
-        flat = scatter_accumulate(keys_s, vals_s, m * n)
+        flat = scatter_accumulate(plan.sorted_keys, plan.sorted_vals, m * n)
     with obs.stage("spkadd.output"):
         return _canonical_from_plan(cat, plan, flat)
 
@@ -388,10 +389,9 @@ def _partitioned_core(keys: jax.Array, vals: jax.Array,
     with obs.stage("spkadd.plan"):
         plan, keys_p, steps = jax.vmap(functools.partial(
             plan_and_partition, shape=shape, part_elems=geom.part_elems,
-            chunk=geom.chunk))(keys)
-        vals_srt = jnp.take_along_axis(vals, plan.order, axis=-1)
+            chunk=geom.chunk))(keys, vals=vals)
         vals_p = jnp.zeros(keys_p.shape, jnp.float32).at[:, :cap].set(
-            vals_srt.astype(jnp.float32))
+            plan.sorted_vals.astype(jnp.float32))
     with obs.stage("spkadd.accumulate"):
         acc = kops.partitioned_accumulate(
             keys_p, vals_p, steps.chunk_id, steps.part_id, m=m, n=n,
@@ -467,7 +467,7 @@ def _hash_core(keys: jax.Array, vals: jax.Array, shape: Tuple[int, int],
     per-key value is exactly the canonical left fold — so compacting the
     tables (occupied slots sorted by key, sentinel padding, structural
     ``nnz``) reproduces the canonical PaddedCOO bit-for-bit. That
-    compaction's ``stable_argsort`` is the single counted sort of a hash
+    compaction's ``stable_sort_pairs`` is the single counted sort of a hash
     dispatch; the ``engine.hash.presort_sorts`` gauge (pinned at zero)
     certifies nothing sorted before the tables were built. Shared by the
     single-collection regime (B = 1) and :func:`spkadd_batched`.
@@ -489,16 +489,14 @@ def _hash_core(keys: jax.Array, vals: jax.Array, shape: Tuple[int, int],
     obs.gauge("engine.hash.presort_sorts").set(sort_calls() - sorts_before)
 
     # compaction — the ONE stable sort of a hash dispatch. Part tables are
-    # key-range ordered, so a single batched argsort over the concatenated
-    # tables yields canonical order; the stable tie-break keeps sentinel
-    # (empty) slots behind every real key.
+    # key-range ordered, so a single batched sort of (key, value) over the
+    # concatenated tables yields canonical order; the stable tie-break keeps
+    # sentinel (empty) slots behind every real key.
     obs.counter("engine.hash.compaction_sorts").inc()
     with obs.stage("spkadd.output"):
         occupied = tkeys != -1
         ck = jnp.where(occupied, tkeys, sent)
-        order = stable_argsort(ck)
-        ck_s = jnp.take_along_axis(ck, order, axis=-1)
-        cv_s = jnp.take_along_axis(tvals, order, axis=-1)
+        ck_s, cv_s = stable_sort_pairs(ck, tvals)
         tab = ck.shape[-1]
         if tab >= cap:
             out_keys = ck_s[:, :cap]
@@ -715,7 +713,7 @@ def _run_partitioned_batched(stacked_mats: Sequence[PaddedCOO], regime: str,
     """Batched one-pass partitioned launch: B sorted streams, per-batch step
     tables, ONE Pallas program with a leading batch grid dimension — the
     shared :func:`_partitioned_core` pipeline at B > 1. The single stable
-    sort per call is preserved (one vmapped argsort)."""
+    sort per call is preserved (one vmapped key-value sort)."""
     keys, vals = _batched_streams(stacked_mats)
     return _partitioned_core(keys, vals, stacked_mats[0].shape, regime,
                              vmem_budget_bytes, cost_model)

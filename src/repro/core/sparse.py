@@ -33,31 +33,39 @@ def next_pow2(x: int) -> int:
 
 
 #: Trace-time counter of stable key sorts issued through
-#: :func:`stable_argsort`, on the obs metrics registry (it survives
+#: :func:`stable_argsort`, :func:`stable_sort` and
+#: :func:`stable_sort_pairs`, on the obs metrics registry (it survives
 #: ``obs.metrics.reset()`` — the handle stays registered). Observability
 #: for the engine's single-sort discipline: the one-pass partitioned
 #: regimes promise exactly one stable sort per ``spkadd_auto`` call (the
-#: canonical plan's argsort, shared with the stream partition), and tests
-#: assert the delta across a call.
+#: canonical plan's, shared with the stream partition), and tests assert
+#: the delta across a call.
 SORT_COUNTER_NAME = "sparse.stable_argsort.calls"
 _SORT_COUNTER = _metrics.counter(SORT_COUNTER_NAME)
 
+#: Trace-time counter of the sorts among those that carry their values
+#: with the keys (:func:`stable_sort_pairs`), so that no permutation is
+#: gathered after them: one per canonical plan in every engine regime.
+PAYLOAD_SORT_COUNTER_NAME = "sparse.payload_sorts"
+_PAYLOAD_SORT_COUNTER = _metrics.counter(PAYLOAD_SORT_COUNTER_NAME)
+
 
 def sort_calls() -> int:
-    """Number of :func:`stable_argsort` invocations so far (trace-time).
+    """Number of counted stable sorts issued so far (trace-time).
     Back-compat alias for ``obs.counter("sparse.stable_argsort.calls")``."""
     return _SORT_COUNTER.value
 
 
 def stable_argsort(keys: jax.Array, axis: int = -1) -> jax.Array:
-    """The *one* stable key sort every canonical path goes through.
+    """Counted stable argsort, for callers that need the permutation itself.
 
-    Routing all key argsorts here keeps the sort-count observable
+    Routing every sort through this module keeps the sort-count observable
     (:func:`sort_calls`): the partitioned one-pass regimes must issue
     exactly one — the compress plan's — per engine call. This module is the
     single sanctioned home for direct ``jnp.sort``/``jnp.argsort`` calls
-    (spkaddlint rule SPK101); everything else routes through here or
-    :func:`stable_sort`.
+    (spkaddlint rule SPK101); everything else routes through here,
+    :func:`stable_sort` or :func:`stable_sort_pairs`. A caller that only
+    permutes arrays by the result wants :func:`stable_sort_pairs`.
     """
     _SORT_COUNTER.inc()
     return jnp.argsort(keys, axis=axis, stable=True)
@@ -69,6 +77,22 @@ def stable_sort(keys: jax.Array, axis: int = -1) -> jax.Array:
     repo shows up on the same ``sparse.stable_argsort.calls`` counter."""
     _SORT_COUNTER.inc()
     return jnp.sort(keys, axis=axis, stable=True)
+
+
+def stable_sort_pairs(keys: jax.Array, *payloads: jax.Array,
+                      axis: int = -1) -> Tuple[jax.Array, ...]:
+    """Counted stable sort of ``keys`` that carries ``payloads`` along:
+    ``(keys[order], *(p[order] for p in payloads))`` for
+    ``order = stable_argsort(keys)``, bit for bit, as one sort and no
+    gather. An argsort is itself a sort of ``(keys, iota)``; the payloads
+    take the iota's place, which saves the random gathers through the
+    permutation. (On a TPU the compiler adds an iota of its own to a stable
+    sort with other payloads: one more operand, far cheaper than the
+    gathers.)"""
+    _SORT_COUNTER.inc()
+    _PAYLOAD_SORT_COUNTER.inc()
+    return tuple(jax.lax.sort((keys, *payloads), dimension=axis,
+                              is_stable=True, num_keys=1))
 
 
 def sentinel_key(shape: Tuple[int, int]) -> int:
@@ -164,40 +188,43 @@ def from_dense(dense: jax.Array, cap: int) -> PaddedCOO:
     vals = jnp.where(valid, v, 0.0)
     nnz = valid.sum().astype(jnp.int32)
     # keep sorted by key for the merge-based algorithms
-    order = stable_argsort(keys)
-    out = PaddedCOO(keys=keys[order], vals=vals[order], nnz=nnz, shape=(m, n))
+    keys, vals = stable_sort_pairs(keys, vals)
+    out = PaddedCOO(keys=keys, vals=vals, nnz=nnz, shape=(m, n))
     if cap > k:
         out = with_capacity(out, cap)
     return out
 
 
 def sort_by_key(a: PaddedCOO) -> PaddedCOO:
-    order = stable_argsort(a.keys)
-    return a._replace(keys=a.keys[order], vals=a.vals[order])
+    keys, vals = stable_sort_pairs(a.keys, a.vals)
+    return a._replace(keys=keys, vals=vals)
 
 
 class CompressPlan(NamedTuple):
     """The *structural* half of :func:`compress` — everything that depends on
-    keys only. Factored out so the engine's SPA/blocked-SPA regimes can pair
-    this exact canonical key layout (sorted distinct keys, sentinel padding,
-    structural ``nnz``) with values produced by a dense accumulator instead of
-    a segment-sum, and still emit bit-identical PaddedCOOs.
+    keys only, plus the values carried to sorted order by the same sort.
+    Factored out so the engine's SPA/blocked-SPA regimes can pair this exact
+    canonical key layout (sorted distinct keys, sentinel padding, structural
+    ``nnz``) with values produced by a dense accumulator instead of a
+    segment-sum, and still emit bit-identical PaddedCOOs.
     """
 
-    order: jax.Array     # int[cap]  argsort permutation of the input keys
-    gid: jax.Array       # int[cap]  output group id per sorted slot
-    is_new: jax.Array    # bool[cap] first-occurrence flag per sorted slot
-    out_keys: jax.Array  # int32[cap] canonical key layout (sorted + sentinel)
-    nnz: jax.Array       # int32[]   structural distinct-key count
+    sorted_keys: jax.Array  # int[cap]   the input keys, stably sorted
+    sorted_vals: jax.Array  # [cap]      their values, in the same order
+    gid: jax.Array          # int[cap]   output group id per sorted slot
+    is_new: jax.Array       # bool[cap]  first-occurrence flag per sorted slot
+    out_keys: jax.Array     # int32[cap] canonical key layout (sorted + sentinel)
+    nnz: jax.Array          # int32[]    structural distinct-key count
 
 
-def compress_plan(keys: jax.Array, shape: Tuple[int, int]) -> CompressPlan:
+def compress_plan(keys: jax.Array, shape: Tuple[int, int],
+                  vals: jax.Array) -> CompressPlan:
     """Sort keys, flag first occurrences, and lay out the canonical output
-    key array (paper Alg. 6's symbolic phase, vectorized)."""
+    key array (paper Alg. 6's symbolic phase, vectorized). The one stable
+    sort carries ``vals`` to ``sorted_vals``."""
     cap = keys.shape[0]
     sent = sentinel_key(shape)
-    order = stable_argsort(keys)
-    k_s = keys[order]
+    k_s, v_s = stable_sort_pairs(keys, vals)
     valid = k_s != sent
     first = jnp.concatenate([jnp.ones((1,), bool), k_s[1:] != k_s[:-1]])
     is_new = first & valid
@@ -207,8 +234,8 @@ def compress_plan(keys: jax.Array, shape: Tuple[int, int]) -> CompressPlan:
     scatter_idx = jnp.where(is_new, gid, cap)  # index cap drops out of range
     out_keys = out_keys.at[scatter_idx].set(k_s, mode="drop")
     nnz = is_new.sum().astype(jnp.int32)
-    return CompressPlan(order=order, gid=gid, is_new=is_new,
-                        out_keys=out_keys, nnz=nnz)
+    return CompressPlan(sorted_keys=k_s, sorted_vals=v_s, gid=gid,
+                        is_new=is_new, out_keys=out_keys, nnz=nnz)
 
 
 class PartitionSteps(NamedTuple):
@@ -282,7 +309,7 @@ def partition_steps(keys_sorted: jax.Array, *, mn: int, part_elems: int,
 
 
 def plan_and_partition(keys: jax.Array, shape: Tuple[int, int], *,
-                       part_elems: int, chunk: int
+                       vals: jax.Array, part_elems: int, chunk: int
                        ) -> Tuple[CompressPlan, jax.Array, PartitionSteps]:
     """ONE stable sort shared by the canonical plan and the stream partition.
 
@@ -297,16 +324,17 @@ def plan_and_partition(keys: jax.Array, shape: Tuple[int, int], *,
     makes the single-sort discipline possible.
 
     Returns ``(plan, keys_sorted_padded, steps)``: the canonical plan (its
-    ``order`` re-sorts the values), the sorted key stream padded to a chunk
-    multiple with sentinels, and the per-step partition schedule.
+    ``sorted_vals`` are ``vals`` in plan order, carried by the same sort),
+    the sorted key stream padded to a chunk multiple with sentinels, and the
+    per-step partition schedule.
     """
     m, n = shape
     cap = keys.shape[0]
-    plan = compress_plan(keys, shape)
+    plan = compress_plan(keys, shape, vals)
     cap_pad = ((max(cap, 1) + chunk - 1) // chunk) * chunk
     sent = sentinel_key(shape)
     keys_p = jnp.full((cap_pad,), sent, jnp.int32).at[:cap].set(
-        keys[plan.order].astype(jnp.int32))
+        plan.sorted_keys.astype(jnp.int32))
     parts = (m * n + part_elems - 1) // part_elems
     steps = partition_steps(keys_p, mn=m * n, part_elems=part_elems,
                             parts=max(parts, 1), chunk=chunk)
@@ -320,9 +348,9 @@ def compress(a: PaddedCOO) -> PaddedCOO:
     capacity stays ``a.cap`` (the symbolic bound), ``nnz`` becomes the exact
     count of distinct keys.
     """
-    plan = compress_plan(a.keys, a.shape)
-    v_s = a.vals[plan.order]
-    out_vals = jax.ops.segment_sum(v_s, plan.gid, num_segments=a.cap)
+    plan = compress_plan(a.keys, a.shape, a.vals)
+    out_vals = jax.ops.segment_sum(plan.sorted_vals, plan.gid,
+                                   num_segments=a.cap)
     # zero padding values beyond nnz (groups past nnz hold only padding sums)
     slot = jnp.arange(a.cap)
     out_vals = jnp.where(slot < plan.nnz, out_vals, 0.0)

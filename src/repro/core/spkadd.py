@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sparse import (PaddedCOO, compress, concat, sentinel_key,
-                               stable_argsort, stable_sort, with_capacity)
+                               stable_sort, stable_sort_pairs, with_capacity)
 
 
 # ---------------------------------------------------------------------------
@@ -122,8 +122,8 @@ def _resparsify_flat(flat: jax.Array, shape, out_cap: int) -> PaddedCOO:
     vals = flat[idx]
     valid = vals != 0.0
     keys = jnp.where(valid, idx.astype(jnp.int32), sentinel_key(shape))
-    order = stable_argsort(keys)
-    return PaddedCOO(keys=keys[order], vals=jnp.where(valid, vals, 0.0)[order],
+    keys, vals = stable_sort_pairs(keys, jnp.where(valid, vals, 0.0))
+    return PaddedCOO(keys=keys, vals=vals,
                      nnz=valid.sum().astype(jnp.int32), shape=shape)
 
 

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.engine import spkadd_batched_ragged, spkadd_run
 from repro.core.sparse import (PaddedCOO, make_empty, sentinel_key,
-                               stable_argsort)
+                               stable_sort_pairs)
 
 
 def truncate_by_magnitude(a: PaddedCOO, cap: int) -> PaddedCOO:
@@ -50,9 +50,8 @@ def truncate_by_magnitude(a: PaddedCOO, cap: int) -> PaddedCOO:
     keys = a.keys[idx]
     vals = a.vals[idx]
     valid = keys != sent
-    vals = jnp.where(valid, vals, 0.0)
-    order = stable_argsort(keys)
-    return PaddedCOO(keys=keys[order], vals=vals[order],
+    keys, vals = stable_sort_pairs(keys, jnp.where(valid, vals, 0.0))
+    return PaddedCOO(keys=keys, vals=vals,
                      nnz=jnp.minimum(a.nnz, valid.sum()).astype(jnp.int32),
                      shape=a.shape)
 

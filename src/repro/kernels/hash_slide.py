@@ -4,7 +4,7 @@ The paper's headline result (Tables 3/4) is that hash-based SpKAdd attains
 both the computational and the I/O lower bounds and beats sort-based
 accumulation whenever the compression factor is low, using the hash-vector
 technique of Nagasaka et al. (KNL SpGEMM). Every other engine regime pays
-``sparse.stable_argsort`` over the concatenated stream *before* it
+the plan's stable sort over the concatenated stream *before* it
 accumulates; this kernel pays **zero sorts before compaction**:
 
 - Linear-probing tables live in VMEM output blocks, one ``(rows, 128)``
@@ -21,7 +21,7 @@ accumulates; this kernel pays **zero sorts before compaction**:
   power of two, load factor <= 0.5, probes bounded by ``table_size``.
 - Compaction to canonical order (sorted distinct keys, sentinel padding)
   happens exactly once at the very end, in the engine — the single counted
-  ``stable_argsort`` of a ``hash`` dispatch.
+  stable sort of a ``hash`` dispatch.
 
 When ``parts == 1`` (the full table fits the VMEM budget — the common case
 the cost model gates on), every input chunk is DMA'd exactly once and each

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.compat import interpret_kernels
 from repro.core.sparse import next_pow2 as _next_pow2
-from repro.core.sparse import stable_argsort as _stable_argsort
+from repro.core.sparse import stable_sort_pairs as _stable_sort_pairs
 from repro.kernels import VMEM_BUDGET_BYTES
 from repro.kernels import hash_accum as _hash
 from repro.kernels import spa_accum as _spa
@@ -147,16 +147,14 @@ def vec_accumulate(keys: jax.Array, vals: jax.Array, *, m: int, n: int,
     The stream is **pre-sorted by key (stable)** before launch. That makes
     the fold bit-identical to the canonical ``compress_plan`` contract
     (stream-order per-key sums) regardless of the input order — the stable
-    sort is exactly the plan's ``argsort``, so duplicates keep their stream
-    order and runs never fragment across in-chunk masking.
+    sort is exactly the plan's, so duplicates keep their stream order and
+    runs never fragment across in-chunk masking.
     """
     sent = jnp.int32(m * n)
     valid = keys < m * n
     keys_c = jnp.where(valid, keys, sent).astype(jnp.int32)
     vals_c = jnp.where(valid, vals.astype(jnp.float32), 0.0)
-    order = _stable_argsort(keys_c)
-    keys_s = keys_c[order]
-    vals_s = vals_c[order]
+    keys_s, vals_s = _stable_sort_pairs(keys_c, vals_c)
 
     cap = keys.shape[0]
     block_rows, chunk = vec_launch_geometry(
@@ -324,9 +322,11 @@ def hash_accumulate(keys: jax.Array, vals: jax.Array, *, sent: int,
     tkeys, tvals = _hash.hash_accumulate_raw(keys, vals, sent=sent,
                                              table_size=table_size)
     occupied = tkeys != -1
-    order = _stable_argsort(jnp.logical_not(occupied))
-    ck = jnp.where(occupied[order], tkeys[order], sent)[:cap]
-    cv = jnp.where(occupied[order], tvals[order], 0.0)[:cap]
+    _, tk_s, tv_s = _stable_sort_pairs(jnp.logical_not(occupied), tkeys,
+                                       tvals)
+    occ_s = tk_s != -1
+    ck = jnp.where(occ_s, tk_s, sent)[:cap]
+    cv = jnp.where(occ_s, tv_s, 0.0)[:cap]
     nnz = occupied.sum().astype(jnp.int32)
     return ck.astype(jnp.int32), cv, nnz
 

@@ -10,7 +10,7 @@ module restores the one-pass discipline of the paper's Alg. 8:
 1. **One shared sort.** The accumulator is partitioned into key-aligned
    ranges (``part = key // part_elems``), so the composite partition key
    ``part * (m*n) + key`` is monotone in ``key`` and the canonical
-   ``compress_plan`` argsort doubles as the partition sort
+   ``compress_plan`` sort doubles as the partition sort
    (:func:`repro.core.sparse.plan_and_partition`). The `vec` regime's old
    duplicate sort (plan + in-wrapper pre-sort) collapses to one.
 

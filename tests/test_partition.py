@@ -63,9 +63,9 @@ def run_partitioned(keys, vals, *, m, n, part_elems, chunk, fold):
                                             part_elems=part_elems,
                                             chunk=chunk)
     plan, keys_p, steps = S.plan_and_partition(
-        keys, (m, n), part_elems=geom.part_elems, chunk=geom.chunk)
+        keys, (m, n), part_elems=geom.part_elems, chunk=geom.chunk, vals=vals)
     vals_p = jnp.zeros(keys_p.shape, jnp.float32).at[:len(keys)].set(
-        vals[plan.order].astype(jnp.float32))
+        plan.sorted_vals.astype(jnp.float32))
     acc = kops.partitioned_accumulate(
         keys_p, vals_p, steps.chunk_id, steps.part_id, m=m, n=n,
         part_elems=geom.part_elems, parts=geom.parts, chunk=geom.chunk,
@@ -170,9 +170,10 @@ def test_engine_partitioned_multi_part_geometry():
     assert geom.parts > 1
     cat = S.concat(mats)
     plan, keys_p, steps = S.plan_and_partition(
-        cat.keys, cat.shape, part_elems=geom.part_elems, chunk=geom.chunk)
+        cat.keys, cat.shape, part_elems=geom.part_elems, chunk=geom.chunk,
+        vals=cat.vals)
     vals_p = jnp.zeros(keys_p.shape, jnp.float32).at[:cat.cap].set(
-        cat.vals[plan.order])
+        plan.sorted_vals)
     acc = kops.partitioned_accumulate(
         keys_p, vals_p, steps.chunk_id, steps.part_id, m=64, n=8,
         part_elems=geom.part_elems, parts=geom.parts, chunk=geom.chunk,
@@ -184,7 +185,7 @@ def test_engine_partitioned_multi_part_geometry():
 
 def test_engine_single_stable_sort_per_call():
     """The acceptance contract: one stable sort per spkadd_auto call in the
-    partitioned regimes (the plan's argsort, shared with the partition) —
+    partitioned regimes (the plan's sort, shared with the partition) —
     the old vec path paid two (plan + in-wrapper pre-sort)."""
     mats = random_collection(13, 8, 48, 8, 36)
     for force in (FORCE_VEC, FORCE_BLOCKED):
