@@ -165,6 +165,17 @@ def geometry_matrix() -> Iterable[Tuple[str, Callable[[], object], int]]:
                        E.spkadd_auto(mats, cost_model=dict(force)),
                        expected_sorts(regime, k))
 
+    # wide keys (m*n >= 2**31): one multi-key sort of both words, at any k
+    from repro.core.sparse import from_coords
+    wide = (1 << 16, 1 << 16)
+    for k in ks:
+        idx = jnp.arange(8, dtype=jnp.int32)
+        mats = [from_coords(idx * 4099 % wide[0], (idx + i) % 3 * 21845,
+                            jnp.ones((8,), jnp.float32), wide, nnz=6)
+                for i in range(k)]
+        yield (f"spkadd_auto[wide,k={k}]",
+               lambda mats=mats: E.spkadd_auto(mats), 1)
+
     # batched: one vmapped sort for the whole stack (hash: the single
     # batched compaction sort — still one)
     colls = [_collection(100 + b, 4, 32, 8, 24) for b in range(3)]

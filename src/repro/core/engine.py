@@ -75,9 +75,11 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.core.sparse import (CompressPlan, PaddedCOO, compress_plan, concat,
-                               next_pow2, plan_and_partition, sentinel_key,
-                               sort_calls, stable_sort_pairs, with_capacity)
+from repro.core.sparse import (WIDE_KEY_SPACE, CompressPlan, PaddedCOO,
+                               compress_plan, concat, is_wide, next_pow2,
+                               plan_and_partition, require_narrow,
+                               sentinel_key, sort_calls, stable_sort_pairs,
+                               with_capacity)
 from repro.core import spkadd as _alg
 from repro.kernels import VMEM_BUDGET_BYTES
 
@@ -215,7 +217,14 @@ def default_cost_model() -> Dict[str, float]:
 
 def select_algorithm(signals: RegimeSignals,
                      cost_model: Optional[Dict[str, float]] = None) -> str:
-    """Map regime signals to the Fig. 2 region winner."""
+    """Map regime signals to the Fig. 2 region winner.
+
+    A key space of ``2**31`` or more (two-word keys, ``sparse.WideKeys``)
+    goes to ``sorted`` whatever the cost model says: the one regime whose
+    single sort takes both words. Every other regime keeps int32 keys, and
+    ``tree`` would sort once per 2-way add."""
+    if signals.accum_elems >= WIDE_KEY_SPACE:
+        return "sorted"
     cm = default_cost_model()
     if cost_model:
         cm.update(cost_model)
@@ -337,6 +346,7 @@ def _run_spa(mats: Sequence[PaddedCOO],
     combines duplicate indices unspecified, and on a TPU an unsorted
     scatter does not add them in stream order, while a key-sorted one folds
     them as ``segment_sum`` does in the ``sorted`` regime."""
+    require_narrow(mats[0].shape, "the spa regime")
     with obs.stage("spkadd.plan"):
         cat = concat(mats)
         m, n = cat.shape
@@ -379,6 +389,7 @@ def _partitioned_core(keys: jax.Array, vals: jax.Array,
     bit-identity contract between them is structural, not tested-for."""
     from repro.kernels import ops as kops  # kernels are optional deps
 
+    require_narrow(shape, f"the {regime} regime")
     m, n = shape
     cap = keys.shape[-1]
     geom = kops.partitioned_launch_geometry(
@@ -474,6 +485,7 @@ def _hash_core(keys: jax.Array, vals: jax.Array, shape: Tuple[int, int],
     """
     from repro.kernels import ops as kops  # kernels are optional deps
 
+    require_narrow(shape, "the hash regime")
     m, n = shape
     B, cap = keys.shape
     sent = sentinel_key(shape)
@@ -562,9 +574,27 @@ def _run_tree(mats: Sequence[PaddedCOO],
     return PaddedCOO(*out, shape)
 
 
+def _run_sorted(mats: Sequence[PaddedCOO],
+                cost_model: Optional[Dict[str, float]] = None) -> PaddedCOO:
+    """k-way merge regime (``spkadd.spkadd_sorted``, staged): the plan's one
+    stable sort and first-occurrence flags, the segment sum of each key's
+    run, the canonical values. The one regime that adds wide keys: the
+    plan sorts both words in one multi-key sort."""
+    with obs.stage("spkadd.plan"):
+        cat = concat(mats)
+        plan = compress_plan(cat.keys, cat.shape, cat.vals)
+    with obs.stage("spkadd.accumulate"):
+        sums = jax.ops.segment_sum(plan.sorted_vals, plan.gid,
+                                   num_segments=cat.cap)
+    with obs.stage("spkadd.output"):
+        vals = jnp.where(jnp.arange(cat.cap) < plan.nnz, sums, 0.0)
+    return PaddedCOO(keys=plan.out_keys, vals=vals, nnz=plan.nnz,
+                     shape=cat.shape)
+
+
 def _unphased(run):
-    """A regime with no separate plan and output phases (``tree``,
-    ``sorted``): its whole call is named ``spkadd.accumulate``."""
+    """A regime with no separate plan and output phases (``tree``): its
+    whole call is named ``spkadd.accumulate``."""
     def staged(mats, cost_model=None):
         with obs.stage("spkadd.accumulate"):
             return run(mats, cost_model=cost_model)
@@ -578,8 +608,7 @@ def _unphased(run):
 #: overrides reach every regime uniformly.
 _CANONICAL = {
     "tree": _unphased(_run_tree),
-    "sorted": _unphased(lambda mats, cost_model=None:
-                        _alg.spkadd_sorted(mats)),
+    "sorted": _run_sorted,
     "spa": _run_spa,
     "vec": _run_vec,
     "blocked_spa": _run_blocked_spa,
@@ -602,7 +631,8 @@ def spkadd_auto(mats: Sequence[PaddedCOO], *,
     obs.counter(f"engine.dispatch.{selected}").inc()
     with obs.span("engine.spkadd_auto", selected=selected, k=sig.k,
                   density=sig.density, compression=sig.compression,
-                  accum_elems=sig.accum_elems):
+                  accum_elems=sig.accum_elems,
+                  wide_keys=is_wide(mats[0].shape)):
         return _CANONICAL[selected](mats, cost_model=cost_model)
 
 
@@ -646,7 +676,8 @@ def stack_collections(collections: Sequence[Sequence[PaddedCOO]]
                 raise ValueError("stacked collections must share a shape")
     return [
         PaddedCOO(
-            keys=jnp.stack([coll[i].keys for coll in collections]),
+            keys=jax.tree.map(lambda *ks: jnp.stack(ks),
+                              *[coll[i].keys for coll in collections]),
             vals=jnp.stack([coll[i].vals for coll in collections]),
             nnz=jnp.stack([jnp.asarray(coll[i].nnz, jnp.int32)
                            for coll in collections]),
@@ -658,7 +689,8 @@ def stack_collections(collections: Sequence[Sequence[PaddedCOO]]
 
 def unstack_collection(batched: Sequence[PaddedCOO], b: int) -> List[PaddedCOO]:
     """Slice batch element ``b`` back out of a stacked collection/result."""
-    return [PaddedCOO(a.keys[b], a.vals[b], a.nnz[b], a.shape)
+    return [PaddedCOO(jax.tree.map(lambda k: k[b], a.keys), a.vals[b],
+                      a.nnz[b], a.shape)
             for a in batched]
 
 

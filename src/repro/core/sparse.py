@@ -9,10 +9,16 @@ safe (padding contributes nothing wherever it lands).
 Keys are linearized in CSC order (``key = col * m + row``) to match the
 paper's column-major traversal; a sorted PaddedCOO is therefore sorted the way
 the paper's ColAdd expects its inputs.
+
+A key is one int32 while ``m*n`` and its sentinel fit one (``m*n < 2**31``).
+A wider key space keeps the same CSC order in two int32 words,
+:class:`WideKeys` ``(col, row)``, compared column first; the layout follows
+from the static shape (:func:`is_wide`), and no int64 enters the program.
 """
 from __future__ import annotations
 
 import functools
+import operator
 from typing import NamedTuple, Tuple
 
 import jax
@@ -48,6 +54,46 @@ _SORT_COUNTER = _metrics.counter(SORT_COUNTER_NAME)
 #: gathered after them: one per canonical plan in every engine regime.
 PAYLOAD_SORT_COUNTER_NAME = "sparse.payload_sorts"
 _PAYLOAD_SORT_COUNTER = _metrics.counter(PAYLOAD_SORT_COUNTER_NAME)
+
+
+#: Trace-time counter of the sorts among those over two-word keys
+#: (:class:`WideKeys`): one multi-key sort per wide canonical plan.
+WIDE_SORT_COUNTER_NAME = "sparse.wide_sorts"
+_WIDE_SORT_COUNTER = _metrics.counter(WIDE_SORT_COUNTER_NAME)
+
+#: The smallest ``m*n`` whose keys are wide: from here on neither
+#: ``col*m + row`` nor the sentinel ``m*n`` fits an int32.
+WIDE_KEY_SPACE = 1 << 31
+
+
+def is_wide(shape: Tuple[int, int]) -> bool:
+    """Whether ``shape``'s keys take two words (``m*n >= 2**31``)."""
+    m, n = shape
+    return m * n >= WIDE_KEY_SPACE
+
+
+def require_narrow(shape: Tuple[int, int], what: str) -> None:
+    """Refuse a wide key space in a path that keeps int32 keys."""
+    if is_wide(shape):
+        m, n = shape
+        raise ValueError(
+            f"{what} keeps int32 keys col*m + row, so m*n must stay below "
+            f"2**31; shape {m} x {n} has m*n = {m * n}. spkadd_auto adds "
+            f"such collections (sorted regime, two-word keys)")
+
+
+class WideKeys(NamedTuple):
+    """CSC keys of a wide shape as two int32 words, ordered ``(col, row)``:
+    the same order as ``col*m + row``. Padding is ``(n, 0)``, the
+    decomposition of the sentinel ``m*n``. A pytree, so a PaddedCOO that
+    holds it jits and vmaps as one with int32 keys."""
+
+    col: jax.Array
+    row: jax.Array
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return self.col.shape
 
 
 def sort_calls() -> int:
@@ -88,17 +134,24 @@ def stable_sort_pairs(keys: jax.Array, *payloads: jax.Array,
     take the iota's place, which saves the random gathers through the
     permutation. (On a TPU the compiler adds an iota of its own to a stable
     sort with other payloads: one more operand, far cheaper than the
-    gathers.)"""
+    gathers.) :class:`WideKeys` sort as one multi-key sort, column word
+    first."""
     _SORT_COUNTER.inc()
     _PAYLOAD_SORT_COUNTER.inc()
+    if isinstance(keys, WideKeys):
+        _WIDE_SORT_COUNTER.inc()
+        col, row, *rest = jax.lax.sort((*keys, *payloads), dimension=axis,
+                                       is_stable=True, num_keys=2)
+        return (WideKeys(col, row), *rest)
     return tuple(jax.lax.sort((keys, *payloads), dimension=axis,
                               is_stable=True, num_keys=1))
 
 
-def sentinel_key(shape: Tuple[int, int]) -> int:
-    """Key strictly greater than any valid linearized (row, col)."""
+def sentinel_key(shape: Tuple[int, int]):
+    """Key strictly greater than any valid linearized (row, col): ``m*n``,
+    or its words ``WideKeys(n, 0)`` for a wide shape."""
     m, n = shape
-    return m * n
+    return WideKeys(n, 0) if is_wide(shape) else m * n
 
 
 class PaddedCOO(NamedTuple):
@@ -107,6 +160,7 @@ class PaddedCOO(NamedTuple):
     Fields
     ------
     keys : int32[cap]   linearized ``col*m + row``; ``m*n`` marks padding
+                        (a :class:`WideKeys` pair for a wide shape)
     vals : float[cap]   0.0 in padding slots (invariant)
     nnz  : int32[]      number of valid leading-or-scattered entries
     shape: (m, n)       static logical shape (not traced)
@@ -124,17 +178,20 @@ class PaddedCOO(NamedTuple):
     @property
     def rows(self) -> jax.Array:
         m, _ = self.shape
-        return jnp.where(self.valid_mask(), self.keys % m, m)
+        row = self.keys.row if is_wide(self.shape) else self.keys % m
+        return jnp.where(self.valid_mask(), row, m)
 
     @property
     def cols(self) -> jax.Array:
         m, n = self.shape
-        return jnp.where(self.valid_mask(), self.keys // m, n)
+        col = self.keys.col if is_wide(self.shape) else self.keys // m
+        return jnp.where(self.valid_mask(), col, n)
 
     def valid_mask(self) -> jax.Array:
-        return self.keys != sentinel_key(self.shape)
+        return _is_key(self.keys, self.shape)
 
     def to_dense(self) -> jax.Array:
+        require_narrow(self.shape, "to_dense")
         m, n = self.shape
         flat = jnp.zeros((m * n,), dtype=self.vals.dtype)
         k = jnp.where(self.valid_mask(), self.keys, 0)
@@ -143,10 +200,30 @@ class PaddedCOO(NamedTuple):
         return flat.reshape(n, m).T  # keys are col-major
 
 
-def make_empty(shape: Tuple[int, int], cap: int, dtype=jnp.float32) -> PaddedCOO:
+def _is_key(keys, shape: Tuple[int, int]) -> jax.Array:
+    """Which slots of ``keys`` hold a key, not padding."""
+    if is_wide(shape):
+        return keys.col != shape[1]
+    return keys != sentinel_key(shape)
+
+
+def _full_keys(shape: Tuple[int, int], cap: int):
+    """``cap`` padding keys of ``shape``'s layout."""
     sent = sentinel_key(shape)
+    if is_wide(shape):
+        return WideKeys(jnp.full((cap,), sent.col, jnp.int32),
+                        jnp.zeros((cap,), jnp.int32))
+    return jnp.full((cap,), sent, dtype=jnp.int32)
+
+
+def _concat_keys(keys):
+    """Concatenate key arrays (or :class:`WideKeys`, word by word)."""
+    return jax.tree.map(lambda *ks: jnp.concatenate(ks), *keys)
+
+
+def make_empty(shape: Tuple[int, int], cap: int, dtype=jnp.float32) -> PaddedCOO:
     return PaddedCOO(
-        keys=jnp.full((cap,), sent, dtype=jnp.int32),
+        keys=_full_keys(shape, cap),
         vals=jnp.zeros((cap,), dtype=dtype),
         nnz=jnp.zeros((), dtype=jnp.int32),
         shape=shape,
@@ -159,14 +236,18 @@ def from_coords(rows: jax.Array, cols: jax.Array, vals: jax.Array,
     ``nnz`` is given, in which case trailing slots are padded out."""
     m, n = shape
     cap = rows.shape[0]
-    keys = cols.astype(jnp.int32) * m + rows.astype(jnp.int32)
     if nnz is None:
         nnz = jnp.asarray(cap, dtype=jnp.int32)
     else:
         nnz = jnp.asarray(nnz, dtype=jnp.int32)
     idx = jnp.arange(cap)
     valid = idx < nnz
-    keys = jnp.where(valid, keys, sentinel_key(shape))
+    if is_wide(shape):
+        keys = WideKeys(jnp.where(valid, cols.astype(jnp.int32), n),
+                        jnp.where(valid, rows.astype(jnp.int32), 0))
+    else:
+        keys = cols.astype(jnp.int32) * m + rows.astype(jnp.int32)
+        keys = jnp.where(valid, keys, sentinel_key(shape))
     vals = jnp.where(valid, vals, 0.0)
     return PaddedCOO(keys=keys, vals=vals.astype(vals.dtype), nnz=nnz, shape=shape)
 
@@ -215,24 +296,28 @@ class CompressPlan(NamedTuple):
     is_new: jax.Array       # bool[cap]  first-occurrence flag per sorted slot
     out_keys: jax.Array     # int32[cap] canonical key layout (sorted + sentinel)
     nnz: jax.Array          # int32[]    structural distinct-key count
+    # (the key fields are WideKeys for a wide shape)
 
 
 def compress_plan(keys: jax.Array, shape: Tuple[int, int],
                   vals: jax.Array) -> CompressPlan:
     """Sort keys, flag first occurrences, and lay out the canonical output
     key array (paper Alg. 6's symbolic phase, vectorized). The one stable
-    sort carries ``vals`` to ``sorted_vals``."""
+    sort carries ``vals`` to ``sorted_vals``; wide keys sort as one
+    multi-key sort and are new where either word changes."""
     cap = keys.shape[0]
-    sent = sentinel_key(shape)
     k_s, v_s = stable_sort_pairs(keys, vals)
-    valid = k_s != sent
-    first = jnp.concatenate([jnp.ones((1,), bool), k_s[1:] != k_s[:-1]])
+    valid = _is_key(k_s, shape)
+    changed = functools.reduce(operator.or_, [w[1:] != w[:-1] for w in
+                                              jax.tree.leaves(k_s)])
+    first = jnp.concatenate([jnp.ones((1,), bool), changed])
     is_new = first & valid
     # group id for every slot; padding inherits the last group but adds 0.0
     gid = jnp.clip(jnp.cumsum(is_new) - 1, 0, cap - 1)
-    out_keys = jnp.full((cap,), sent, dtype=jnp.int32)
     scatter_idx = jnp.where(is_new, gid, cap)  # index cap drops out of range
-    out_keys = out_keys.at[scatter_idx].set(k_s, mode="drop")
+    out_keys = jax.tree.map(
+        lambda pad, k: pad.at[scatter_idx].set(k, mode="drop"),
+        _full_keys(shape, cap), k_s)
     nnz = is_new.sum().astype(jnp.int32)
     return CompressPlan(sorted_keys=k_s, sorted_vals=v_s, gid=gid,
                         is_new=is_new, out_keys=out_keys, nnz=nnz)
@@ -364,7 +449,7 @@ def concat(mats, total_cap: int | None = None) -> PaddedCOO:
     for a in mats:
         if a.shape != shape:
             raise ValueError("SpKAdd inputs must share a logical shape")
-    keys = jnp.concatenate([a.keys for a in mats])
+    keys = _concat_keys([a.keys for a in mats])
     vals = jnp.concatenate([a.vals for a in mats])
     nnz = functools.reduce(lambda x, y: x + y, [a.nnz for a in mats])
     out = PaddedCOO(keys=keys, vals=vals, nnz=nnz, shape=shape)
@@ -375,19 +460,19 @@ def concat(mats, total_cap: int | None = None) -> PaddedCOO:
 
 def with_capacity(a: PaddedCOO, cap: int) -> PaddedCOO:
     """Grow (pad) or shrink (sorted-truncate) to a new capacity."""
-    sent = sentinel_key(a.shape)
     if cap == a.cap:
         return a
     if cap > a.cap:
         pad = cap - a.cap
         return PaddedCOO(
-            keys=jnp.concatenate([a.keys, jnp.full((pad,), sent, jnp.int32)]),
+            keys=_concat_keys([a.keys, _full_keys(a.shape, pad)]),
             vals=jnp.concatenate([a.vals, jnp.zeros((pad,), a.vals.dtype)]),
             nnz=a.nnz,
             shape=a.shape,
         )
     s = sort_by_key(a)  # valid keys first
-    return PaddedCOO(keys=s.keys[:cap], vals=s.vals[:cap], nnz=jnp.minimum(a.nnz, cap),
+    return PaddedCOO(keys=jax.tree.map(lambda k: k[:cap], s.keys),
+                     vals=s.vals[:cap], nnz=jnp.minimum(a.nnz, cap),
                      shape=a.shape)
 
 

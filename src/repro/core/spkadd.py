@@ -31,7 +31,8 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.core.sparse import (PaddedCOO, compress, concat, sentinel_key,
+from repro.core.sparse import (PaddedCOO, compress, compress_plan, concat,
+                               is_wide, require_narrow, sentinel_key,
                                stable_sort, stable_sort_pairs, with_capacity)
 
 
@@ -43,8 +44,12 @@ def symbolic_nnz(mats: Sequence[PaddedCOO]) -> jax.Array:
     """Exact nnz of the sum (distinct valid keys across all inputs).
 
     Paper Alg. 6 with the hash table replaced by sort+adjacent-compare; same
-    O(sum nnz) data touched, vectorized.
+    O(sum nnz) data touched, vectorized. Wide keys count through the
+    canonical plan, whose one sort takes both words.
     """
+    if is_wide(mats[0].shape):
+        cat = concat(mats)
+        return compress_plan(cat.keys, cat.shape, cat.vals).nnz
     sent = sentinel_key(mats[0].shape)
     keys = stable_sort(jnp.concatenate([a.keys for a in mats]))
     valid = keys != sent
@@ -132,6 +137,7 @@ def spkadd_spa(mats: Sequence[PaddedCOO], out_cap: int | None = None) -> PaddedC
     re-sparsification. Work-optimal O(sum nnz) scatter, O(m·n) accumulator —
     exactly the paper's memory/work trade."""
     shape = mats[0].shape
+    require_narrow(shape, "spa")
     m, n = shape
     flat = jnp.zeros((m * n,), dtype=mats[0].vals.dtype)
     for a in mats:
@@ -169,6 +175,7 @@ def spkadd_blocked_spa(mats: Sequence[PaddedCOO], block_rows: int | None = None,
     from repro.kernels import ops as kops  # local import: kernels are optional deps
 
     shape = mats[0].shape
+    require_narrow(shape, "blocked_spa")
     m, n = shape
     cat = concat(mats)
     flat = kops.spa_accumulate_flat(cat.keys, cat.vals, m=m, n=n,
@@ -191,6 +198,7 @@ def spkadd_vec(mats: Sequence[PaddedCOO], block_rows: int | None = None,
     from repro.kernels import ops as kops
 
     shape = mats[0].shape
+    require_narrow(shape, "vec")
     m, n = shape
     cat = concat(mats)
     flat = kops.vec_accumulate_flat(cat.keys, cat.vals, m=m, n=n,
@@ -210,6 +218,7 @@ def spkadd_hash(mats: Sequence[PaddedCOO]) -> PaddedCOO:
     from repro.kernels import ops as kops
 
     shape = mats[0].shape
+    require_narrow(shape, "hash")
     cat = concat(mats)
     keys, vals, nnz = kops.hash_accumulate(cat.keys, cat.vals,
                                            sent=sentinel_key(shape))

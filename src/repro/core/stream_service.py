@@ -68,7 +68,7 @@ import numpy as np
 
 from repro import obs
 from repro.core.engine import spkadd_batched_ragged
-from repro.core.sparse import PaddedCOO, make_empty
+from repro.core.sparse import PaddedCOO, make_empty, require_narrow
 from repro.core.streaming import truncate_by_magnitude
 from repro.runtime.faults import backoff_delay
 
@@ -313,6 +313,7 @@ class StreamService:
                              f"got {tenant!r}")
         if tenant in self._streams:
             raise ValueError(f"tenant {tenant!r} already registered")
+        require_narrow(shape, "the stream service")
         if batch_k < 1:
             raise ValueError(f"batch_k must be >= 1, got {batch_k}")
         if not (rate > 0 and burst >= 1):

@@ -36,8 +36,8 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.core.engine import spkadd_batched_ragged, spkadd_run
-from repro.core.sparse import (PaddedCOO, make_empty, sentinel_key,
-                               stable_sort_pairs)
+from repro.core.sparse import (PaddedCOO, make_empty, require_narrow,
+                               sentinel_key, stable_sort_pairs)
 
 
 def truncate_by_magnitude(a: PaddedCOO, cap: int) -> PaddedCOO:
@@ -72,6 +72,7 @@ class StreamingAccumulator:
     def __init__(self, shape: Tuple[int, int], *, batch_k: int = 8,
                  cap_budget: int = 1 << 16, algorithm: str = "auto",
                  window_batch: int = 1, dtype=jnp.float32):
+        require_narrow(shape, "StreamingAccumulator")
         self.shape = shape
         self.batch_k = batch_k
         self.cap_budget = min(cap_budget, shape[0] * shape[1])

@@ -60,7 +60,7 @@ import numpy as np
 from repro import obs
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.core.engine import spkadd_batched_ragged
-from repro.core.sparse import PaddedCOO, make_empty
+from repro.core.sparse import PaddedCOO, make_empty, require_narrow
 from repro.core.topk import global_k, sparsify_with_feedback
 from repro.runtime.faults import backoff_delay
 from repro.sharding.params import ef_shardings
@@ -168,6 +168,7 @@ def frame_to_coo(frame: DeltaFrame) -> PaddedCOO:
     linearized key, sentinel == size — so a missed window folds through
     the engine unchanged."""
     shape = (frame.size, 1)
+    require_narrow(shape, "delta sync")
     n = int(frame.idx.shape[0])
     if n == 0:
         return make_empty(shape, 1)

@@ -223,11 +223,11 @@ FORCE = {
              "hash_min_total_nnz": 0.0, "hash_max_compression": 1e18},
 }
 
-#: the regimes with no separate phases run wholly as ``spkadd.accumulate``
+#: the regime with no separate phases runs wholly as ``spkadd.accumulate``
 REGIME_STAGES = {
-    "tree": {"spkadd.accumulate"}, "sorted": {"spkadd.accumulate"},
+    "tree": {"spkadd.accumulate"},
     **{r: {"spkadd.plan", "spkadd.accumulate", "spkadd.output"}
-       for r in ("spa", "vec", "blocked_spa", "hash")},
+       for r in ("sorted", "spa", "vec", "blocked_spa", "hash")},
 }
 
 CHECKED_OPS = ("stablehlo.sort", "stablehlo.gather", "stablehlo.scatter",
@@ -295,8 +295,9 @@ def test_every_regime_names_its_device_work(regime):
 
 #: the stage that makes a result's keys and nnz: the plan's in the regimes
 #: that keep the plan's key layout, the compaction's in ``hash``
-KEYS_STAGE = {"spa": "spkadd.plan", "vec": "spkadd.plan",
-              "blocked_spa": "spkadd.plan", "hash": "spkadd.output"}
+KEYS_STAGE = {"sorted": "spkadd.plan", "spa": "spkadd.plan",
+              "vec": "spkadd.plan", "blocked_spa": "spkadd.plan",
+              "hash": "spkadd.output"}
 
 
 @pytest.mark.parametrize("regime", sorted(KEYS_STAGE))
